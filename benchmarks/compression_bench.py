@@ -22,7 +22,7 @@ def run() -> Dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.training.compression import compressed_psum_pod
 
     devs = jax.local_device_count()

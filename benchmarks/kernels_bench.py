@@ -102,7 +102,7 @@ def run() -> List[Dict]:
 
     # topk gate at DECODE shapes (the RotaryEngine hot path routes [B, E]
     # per MoE layer per token) + the backend-dispatching route_topk wrapper
-    from repro.kernels.topk_gate import route_topk
+    from repro.kernels.ops import route_topk
 
     for tb in (1, 2, 8):
         logits_d = jnp.asarray(rng.standard_normal((tb, e4)), jnp.float32)

@@ -15,7 +15,7 @@ from repro.configs import (  # noqa: F401
     starcoder2_7b,
     xlstm_350m,
 )
-from repro.configs.reduced import reduce_for_smoke  # noqa: F401
+from repro.configs.reduced import cut_depth, reduce_for_smoke  # noqa: F401
 from repro.configs.shapes import SHAPES, applicable_shapes, shape_applies  # noqa: F401
 
 ASSIGNED_ARCHS = (

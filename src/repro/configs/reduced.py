@@ -1,7 +1,9 @@
-"""Reduced configs for CPU smoke tests: same family/block structure, tiny dims.
+"""Cut-down configs: tiny widths for CPU smoke tests, or published widths at
+a cut depth for a single chip.
 
-The FULL configs are only exercised via the dry-run (ShapeDtypeStruct, no allocation);
-every smoke test instantiates the reduced config and runs a real forward/train step.
+``reduce_for_smoke`` keeps the family/block structure at tiny dims; every
+smoke test instantiates it and runs a real forward/decode. ``cut_depth``
+keeps every width of the published config and cuts only the layer count.
 """
 from __future__ import annotations
 
@@ -73,4 +75,27 @@ def reduce_for_smoke(
         d_ff=128 if cfg.d_ff else 0,
         frontend_len=8 if cfg.frontend else 0,
         frontend_dim=d_model if cfg.frontend else 0,
+    )
+
+
+def cut_depth(cfg: ModelConfig, layers: int) -> ModelConfig:
+    """The published config with only its first ``layers`` layers, rounded
+    down to whole segment units (every width, the expert count, top-k and
+    vocab kept)."""
+    if layers < 1:
+        raise ValueError(f"need at least one layer, got {layers}")
+    segments, left = [], layers
+    for unit, reps in cfg.segments:
+        take = min(reps, left // len(unit))
+        if take:
+            segments.append((unit, take))
+            left -= take * len(unit)
+        if take < reps:
+            break
+    if not segments:
+        raise ValueError(f"{cfg.name}: no whole unit fits in {layers} layers")
+    if tuple(segments) == cfg.segments:
+        return cfg
+    return dataclasses.replace(
+        cfg, name=f"{cfg.name}-{layers}L", segments=tuple(segments)
     )

@@ -4,7 +4,7 @@ Execution per decode step (the paper's §4 loop, DESIGN.md §2 "engine path"):
 
   embed -> for each layer:
     attn half (device jit) -> fused router top-k ON DEVICE (Pallas topk_gate on
-    TPU/GPU, lax.top_k elsewhere) -> gathered slot compute against the
+    TPU, lax.top_k on CPU) -> gathered slot compute against the
     persistent device LUT (misses classified in-kernel, dropped) ->
     pre-gating: layer l's hidden predicts layer l+1's demand; the manager
     rotates l+1's slots and issues uploads BEFORE l+1 executes (double-buffered
@@ -124,7 +124,7 @@ from repro.core.predictor import DemandPredictor, host_topk_route
 from repro.core.residency import RotaryResidencyManager
 from repro.core.stats import EngineStats
 from repro.core.transfer import CostModel, TransferClock
-from repro.kernels.topk_gate import route_topk
+from repro.kernels.ops import route_topk
 from repro.models import transformer as tfm
 from repro.models import moe as moe_mod
 from repro.models import sampling as sampling_mod
@@ -135,16 +135,18 @@ from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracer import resolve_tracer
 
 
-def _np_ffn(hw: Dict[str, np.ndarray], e: int, x: np.ndarray) -> np.ndarray:
-    """Host expert GEMM (the paper's CPU-resident expert execution)."""
+def _np_ffn(w: Dict[str, np.ndarray], x: np.ndarray) -> np.ndarray:
+    """Host GEMM of ONE expert (the paper's CPU-resident expert execution):
+    its weights are upcast to f32 here, per call — the warehouse itself stays
+    in the model dtype."""
     xf = x.astype(np.float32)
-    if "w_gate" in hw:
-        g = xf @ hw["w_gate"][e].astype(np.float32)
-        h = (g / (1.0 + np.exp(-g))) * (xf @ hw["w_up"][e].astype(np.float32))
+    if "w_gate" in w:
+        g = xf @ w["w_gate"].astype(np.float32)
+        h = (g / (1.0 + np.exp(-g))) * (xf @ w["w_up"].astype(np.float32))
     else:
-        u = xf @ hw["w_up"][e].astype(np.float32)
+        u = xf @ w["w_up"].astype(np.float32)
         h = 0.5 * u * (1.0 + np.tanh(np.sqrt(2 / np.pi) * (u + 0.044715 * u**3)))
-    return h @ hw["w_down"][e].astype(np.float32)
+    return h @ w["w_down"].astype(np.float32)
 
 
 def moe_segments(cfg: ModelConfig) -> List[int]:
@@ -167,6 +169,43 @@ def concat_route_telemetry(
     return np.concatenate(
         [np.asarray(aux[f"route_{name}/seg{si}"]) for si in moe_segs], axis=axis
     )
+
+
+def split_expert_store(
+    cfg: ModelConfig, params: Any
+) -> Tuple[Any, List[Dict[str, np.ndarray]], List[np.ndarray]]:
+    """Split ``params`` for an engine with a residency manager.
+
+    Returns ``(device_params, host_experts, routers)``: the params tree with
+    every MoE block's routed-expert store removed (what compiled steps take —
+    slot stores supply expert weights), the per-MoE-layer host expert store
+    ``{w_*: [E, ...]}`` in ``cfg.dtype``, and the per-MoE-layer f32 router
+    matrices, both in MoE-ordinal order. Host experts that already are numpy
+    arrays in ``cfg.dtype`` (``init_params(experts_on_host=True)``) are
+    sliced without a copy; device experts are copied to host once."""
+    dtype = np.dtype(jnp.dtype(cfg.dtype))
+    segs: List[Tuple[Any, ...]] = []
+    host_experts: List[Dict[str, np.ndarray]] = []
+    routers: List[np.ndarray] = []
+    for si, (unit, reps) in enumerate(cfg.segments):
+        unit_p = list(params["segments"][si])
+        for pi, kind in enumerate(unit):
+            if kind == "attn_moe":
+                moe = {k: v for k, v in unit_p[pi]["moe"].items() if k != "experts"}
+                unit_p[pi] = {**unit_p[pi], "moe": moe}
+        for r in range(reps):
+            for pi, kind in enumerate(unit):
+                if kind != "attn_moe":
+                    continue
+                experts = params["segments"][si][pi]["moe"]["experts"]
+                host_experts.append({
+                    n: np.asarray(w[r], dtype) for n, w in experts.items()
+                })
+                routers.append(
+                    np.asarray(unit_p[pi]["moe"]["router"][r], np.float32)
+                )
+        segs.append(tuple(unit_p))
+    return {**params, "segments": tuple(segs)}, host_experts, routers
 
 
 def build_fused_decode_step(
@@ -468,50 +507,41 @@ class RotaryEngine:
         self.metrics = MetricsRegistry()
 
         # ---- flatten the layer stack; slice per-layer params -------------
+        # the expert warehouse lives in host memory in EVERY residency mode
+        # (full residency uploads all of it into the slot stores); device
+        # params never carry it
+        dev_params, self.host_experts, routers = split_expert_store(cfg, params)
         self.layers: List[Tuple[str, Any]] = []       # (kind, params)
         self.moe_index: List[Optional[int]] = []      # per layer: MoE ordinal
         self._layer_pos: List[Tuple[int, int, int]] = []   # li -> (si, pi, r)
         self._moe_pos: List[Tuple[int, int]] = []     # MoE ordinal -> (si, r)
         self._moe_layer_li: List[int] = []            # MoE ordinal -> flat li
-        self.host_experts: List[Dict[str, np.ndarray]] = []
-        routers: List[np.ndarray] = []
         moe_ct = 0
         for si, (unit, reps) in enumerate(cfg.segments):
             for r in range(reps):
                 for pi, kind in enumerate(unit):
                     self._layer_pos.append((si, pi, r))
-                    p_l = jax.tree.map(lambda a, r=r: a[r], params["segments"][si][pi])
+                    p_l = jax.tree.map(
+                        lambda a, r=r: a[r], dev_params["segments"][si][pi]
+                    )
                     if kind == "attn_moe":
                         self._moe_pos.append((si, r))
                         self._moe_layer_li.append(len(self.layers))
-                        hw = {
-                            n: np.asarray(w, np.float32)
-                            for n, w in p_l["moe"]["experts"].items()
-                        }
-                        self.host_experts.append(hw)
-                        routers.append(np.asarray(p_l["moe"]["router"], np.float32))
                         self.moe_index.append(moe_ct)
                         moe_ct += 1
-                        if rescfg.mode != "full":
-                            # the warehouse stays in host memory: drop the full
-                            # expert store from device-resident layer params
-                            p_l = dict(p_l)
-                            p_l["moe"] = {
-                                k: v for k, v in p_l["moe"].items() if k != "experts"
-                            }
                     else:
                         self.moe_index.append(None)
                     self.layers.append((kind, p_l))
         self.num_moe_layers = moe_ct
         self.embed_params = {
-            k: params[k]
+            k: dev_params[k]
             for k in ("embed", "final_norm", "lm_head", "frontend_proj")
-            if k in params
+            if k in dev_params
         }
 
-        # per-layer cache for the quantized host-correction weights (built
-        # lazily by _correction_weights on a layer's first miss)
-        self._correct_cache: Dict[int, Dict[str, np.ndarray]] = {}
+        # quantized host-correction rows per (MoE layer, expert), built
+        # lazily by _correction_expert on that expert's first miss
+        self._correct_cache: Dict[Tuple[int, int], Dict[str, np.ndarray]] = {}
         self.predictor = DemandPredictor(routers, ema=rescfg.predictor_ema)
         self.manager = RotaryResidencyManager(
             cfg, rescfg, self.host_experts,
@@ -655,22 +685,7 @@ class RotaryEngine:
             ]
             # stacked decode params: the expert warehouse never rides along —
             # the residency arg supplies expert weights in EVERY mode
-            segs_p = []
-            for si, (unit, reps) in enumerate(cfg.segments):
-                unit_p = []
-                for pi, kind in enumerate(unit):
-                    p_u = params["segments"][si][pi]
-                    if kind == "attn_moe" and "experts" in p_u["moe"]:
-                        p_u = dict(p_u)
-                        p_u["moe"] = {
-                            k: v for k, v in p_u["moe"].items() if k != "experts"
-                        }
-                    unit_p.append(p_u)
-                segs_p.append(tuple(unit_p))
-            self._decode_params = {
-                **{k: v for k, v in params.items() if k != "segments"},
-                "segments": tuple(segs_p),
-            }
+            self._decode_params = dev_params
             self._dstate = None          # stacked decode state (built by prefill)
             # speculative windows: compiled (window, snapshot, rollback) per
             # (K, sample params) — sampled windows draft with on-device draws
@@ -721,8 +736,8 @@ class RotaryEngine:
                 h2 = apply_norm(cfg.norm, p["ln2"], x_mid)
                 logits = moe_mod.router_logits(p["moe"], h2.reshape(-1, x.shape[-1]))
                 if routed:
-                    # fused device routing: Pallas topk_gate on TPU/GPU,
-                    # lax.top_k fallback elsewhere — no host round-trip
+                    # fused device routing: Pallas topk_gate on TPU,
+                    # lax.top_k on CPU — no host round-trip
                     ids, weights = route_topk(
                         logits, m.top_k, normalize=m.norm_topk_prob
                     )
@@ -768,28 +783,30 @@ class RotaryEngine:
     # ------------------------------------------------------------------
     # shared host-side pieces
     # ------------------------------------------------------------------
-    def _correction_weights(self, moe_li: int) -> Dict[str, np.ndarray]:
-        """Host weights the miss correction must GEMM against: the originals,
-        or — under quantization — dequant(quant(w)) through the store's exact
-        jnp ops, so the correction is bit-consistent with what a RESIDENT slot
-        would have computed. Built lazily per layer on first miss (a covered
-        or full-residency engine never pays the pass or the f32 copy)."""
+    def _correction_expert(self, moe_li: int, e: int) -> Dict[str, np.ndarray]:
+        """One expert's host weights for the miss correction: the warehouse
+        rows, or — under quantization — dequant(quant(w)) through the store's
+        exact jnp ops, so the correction is bit-consistent with what a
+        RESIDENT slot would have computed. Quantized rows are memoized per
+        (layer, expert) in the model dtype on first miss (quantization is
+        per expert, so a one-expert pass equals the batched one)."""
+        hw = self.host_experts[moe_li]
         if self.rescfg.quantization is None:
-            return self.host_experts[moe_li]
-        hw = self._correct_cache.get(moe_li)
-        if hw is None:
+            return {n: w[e] for n, w in hw.items()}
+        rows = self._correct_cache.get((moe_li, e))
+        if rows is None:
             from repro.core.slots import fake_quantized_batch
 
             dtype = jnp.dtype(self.cfg.dtype)
-            hw = {
+            rows = {
                 n: fake_quantized_batch(
-                    w, self.rescfg.quantization, dtype,
+                    w[e : e + 1], self.rescfg.quantization, dtype,
                     self.rescfg.quant_group_size,
-                )
-                for n, w in self.host_experts[moe_li].items()
+                )[0].astype(dtype)
+                for n, w in hw.items()
             }
-            self._correct_cache[moe_li] = hw
-        return hw
+            self._correct_cache[(moe_li, e)] = rows
+        return rows
 
     def _host_correct(
         self,
@@ -804,11 +821,10 @@ class RotaryEngine:
         the dequantized weights when the slots are quantized)."""
         h2_np = np.asarray(h2, np.float32).reshape(ids.shape[0], -1)
         corr = np.zeros_like(h2_np)
-        hw = self._correction_weights(moe_li)
         n_host = 0
         for t_i, j in zip(*np.nonzero(miss)):
-            e = int(ids[t_i, j])
-            corr[t_i] += weights[t_i, j] * _np_ffn(hw, e, h2_np[t_i])
+            w = self._correction_expert(moe_li, int(ids[t_i, j]))
+            corr[t_i] += weights[t_i, j] * _np_ffn(w, h2_np[t_i])
             n_host += 1
         x = x + jnp.asarray(corr, x.dtype).reshape(x.shape)
         self.stats.layer(moe_li).host_computed += n_host

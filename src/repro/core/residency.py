@@ -280,10 +280,11 @@ class RotaryResidencyManager:
                 group_size=rescfg.quant_group_size,
             )
             policy = make_policy(rescfg.mode, m.num_experts, slots, rescfg, seed=seed + li)
-            # full policy: preload everything (identity LUT) in one batch
+            # full policy: preload everything (identity LUT) in one batch,
+            # into the fresh buffers (donated: nothing else holds them yet)
             if rescfg.mode == "full":
                 self.stats.bytes_uploaded += store.write_batch(
-                    list(range(m.num_experts)), dict(hw)
+                    list(range(m.num_experts)), dict(hw), donate=True
                 )
             self.stores.append(store)
             self.policies.append(policy)

@@ -87,11 +87,19 @@ def _gmm_kernel_int4(group: int):
 
         # unpack two nibbles per byte in VMEM (the packing invariant lives in
         # repro.quant; the kernel tile is its generic [.., P, F] case)
-        q = unpack_int4(w_ref[0]).astype(jnp.float32)       # [bd, bf]
+        # (widened to int32 first: Mosaic has no uint8 -> f32 cast)
+        q = unpack_int4(w_ref[0].astype(jnp.int32)).astype(jnp.float32)
+        bd, bf = q.shape
+
         # affine dequant BEFORE the dot: scales vary along the contraction
-        # dim, so they cannot fold into the accumulator like int8's
-        s = jnp.repeat(scale_ref[0].astype(jnp.float32), group, axis=0)
-        m = jnp.repeat(mn_ref[0].astype(jnp.float32), group, axis=0)
+        # dim, so they cannot fold into the accumulator like int8's. The
+        # [bd/G, 1, bf] scale/min tiles broadcast over each group's rows.
+        def expand(ref):
+            t = ref[0]
+            return jnp.broadcast_to(t, (t.shape[0], group, bf)).reshape(bd, bf)
+
+        s = expand(scale_ref)
+        m = expand(mn_ref)
         acc_ref[...] += jnp.dot(
             x_ref[0].astype(jnp.float32),
             q * s + m,
@@ -148,23 +156,30 @@ def slot_gmm(
     ]
     kernel = _gmm_kernel
     args = (lut, x, w)
+    # Mosaic tiles the last two block dims in (8, 128) units, so the
+    # scale/min planes get a unit axis before the output-channel axis: their
+    # blocks then end in (1, bf), which equals the array's (1, F) tiling
+    # instead of cutting a row of 1 or bd/G out of an 8-row tile.
     if is_int4:
         in_specs[1] = pl.BlockSpec(
             (1, bd // 2, bf), lambda e, ci, fi, di, lut: (lut[e], di, fi)
         )
-        in_specs.append(pl.BlockSpec(
-            (1, bd // group, bf), lambda e, ci, fi, di, lut: (lut[e], di, fi)
-        ))
-        in_specs.append(pl.BlockSpec(
-            (1, bd // group, bf), lambda e, ci, fi, di, lut: (lut[e], di, fi)
-        ))
+        qspec = pl.BlockSpec(
+            (1, bd // group, 1, bf), lambda e, ci, fi, di, lut: (lut[e], di, 0, fi)
+        )
+        in_specs += [qspec, qspec]
         kernel = _gmm_kernel_int4(group)
-        args = (lut, x, w, scale, mn)
+        # (widened to f32 here: Mosaic cannot load a 16-bit tile one row high)
+        args = (lut, x, w) + tuple(
+            a.astype(jnp.float32)[:, :, None, :] for a in (scale, mn)
+        )
     elif w.dtype == jnp.int8:
         assert scale is not None, "int8 slots require per-channel scales"
-        in_specs.append(pl.BlockSpec((1, bf), lambda e, ci, fi, di, lut: (lut[e], fi)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bf), lambda e, ci, fi, di, lut: (lut[e], 0, fi)
+        ))
         kernel = _gmm_kernel_int8
-        args = (lut, x, w, scale)
+        args = (lut, x, w, scale[:, None, :])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

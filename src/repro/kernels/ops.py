@@ -1,9 +1,10 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On this CPU container kernels run in ``interpret=True`` (the kernel body
-executes in Python — correctness only); on a TPU backend the same calls lower
-through Mosaic. Callers use these wrappers, never the kernels directly, so the
-backend switch is one place.
+On the CPU backend (the test suite) kernels run in ``interpret=True`` (the
+kernel body executes in Python — correctness only); on a TPU backend the same
+calls lower through Mosaic. Any other backend is an error rather than a
+silent interpreter run. Callers use these wrappers, never the kernels
+directly, so the backend switch is one place.
 """
 from __future__ import annotations
 
@@ -19,7 +20,12 @@ from repro.kernels import topk_gate as _tk
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels lower for TPU (or interpret on CPU), not {backend!r}"
+        )
+    return backend == "cpu"
 
 
 def flash_attention(
@@ -75,3 +81,33 @@ def slot_gmm(
 def topk_gate(logits: jax.Array, k: int, *, normalize: bool = True
               ) -> Tuple[jax.Array, jax.Array]:
     return _tk.topk_gate(logits, k, normalize=normalize, interpret=_interpret())
+
+
+def route_topk(
+    logits: jax.Array,              # [T, E]
+    k: int,
+    *,
+    normalize: bool = True,
+) -> Tuple[jax.Array, jax.Array]:
+    """Router gate for the compiled decode/prefill paths (traceable).
+
+    On TPU this lowers the fused Pallas ``topk_gate`` (one VMEM pass, no full
+    sort); on CPU it runs ``jax.lax.top_k`` over a softmax, since an
+    interpreted kernel inside a jitted hot loop is pure overhead. Both break
+    ties lowest-index-first, so routing is backend-independent.
+    """
+    if not _interpret():
+        t, e = logits.shape
+        bt = min(256, t)
+        pad = (-t) % bt
+        if pad:
+            logits = jnp.concatenate(
+                [logits, jnp.full((pad, e), _tk.NEG_INF, logits.dtype)], axis=0
+            )
+        ids, w = _tk.topk_gate(logits, k, normalize=normalize)
+        return ids[:t], w[:t]
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, ids = jax.lax.top_k(probs, k)
+    if normalize:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return ids.astype(jnp.int32), w

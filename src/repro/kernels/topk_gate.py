@@ -44,37 +44,6 @@ def _topk_kernel(x_ref, ids_ref, w_ref, *, k: int, normalize: bool):
     w_ref[...] = w_arr
 
 
-def route_topk(
-    logits: jax.Array,              # [T, E]
-    k: int,
-    *,
-    normalize: bool = True,
-) -> Tuple[jax.Array, jax.Array]:
-    """Backend-dispatching router gate for compiled decode/prefill paths.
-
-    On TPU/GPU this lowers the fused Pallas ``topk_gate`` (one VMEM pass, no
-    full sort); elsewhere it falls back to ``jax.lax.top_k`` over a softmax —
-    interpret-mode Pallas inside a jitted hot loop would be pure overhead on
-    CPU. Both paths break ties lowest-index-first, so routing is
-    backend-independent. Traceable (safe to call inside jit).
-    """
-    if jax.default_backend() in ("tpu", "gpu"):
-        t, e = logits.shape
-        bt = min(256, t)
-        pad = (-t) % bt
-        if pad:
-            logits = jnp.concatenate(
-                [logits, jnp.full((pad, e), NEG_INF, logits.dtype)], axis=0
-            )
-        ids, w = topk_gate(logits, k, normalize=normalize)
-        return ids[:t], w[:t]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, ids = jax.lax.top_k(probs, k)
-    if normalize:
-        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
-    return ids.astype(jnp.int32), w
-
-
 @functools.partial(
     jax.jit, static_argnames=("k", "normalize", "block_t", "interpret")
 )

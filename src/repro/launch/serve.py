@@ -11,6 +11,14 @@ Two engines (DESIGN.md §2):
     a seeded Poisson arrival trace against the live engine (request-level
     joins between window launches) instead of submitting everything up
     front.
+
+Sizes: ``--size smoke`` (the default) shrinks the config to CPU-test widths
+(``reduce_for_smoke``); ``--size full`` keeps the published widths, expert
+count, top-k, vocab and dtype, and ``--layers N`` cuts only the depth. An
+engine with a residency manager gets its experts built one layer at a time
+straight into host memory, so the whole expert set is never on the device.
+``build_engine`` is the construction both this CLI and ``chip_smoke.py``
+call.
 """
 from __future__ import annotations
 
@@ -25,9 +33,15 @@ import numpy as np
 QUANT_CHOICES = {"none": None, "int8": "int8", "int4": "int4"}
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--size", default="smoke", choices=["smoke", "full"],
+                    help="smoke = CPU-test widths; full = the published "
+                         "widths, experts, top-k, vocab and dtype")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="--size full: keep only the first N layers "
+                         "(0 = the published depth)")
     ap.add_argument("--engine", default="batch", choices=["batch", "rotary"])
     ap.add_argument("--residency", default="full",
                     choices=["full", "rotary", "lru", "static"])
@@ -109,47 +123,98 @@ def main() -> None:
                          "so a fixed seed reproduces tokens bitwise across "
                          "runs regardless of batching")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    from repro.config import ResidencyConfig, get_config
-    from repro.configs import reduce_for_smoke
+
+def model_config(args: argparse.Namespace):
+    from repro.config import get_config
+    from repro.configs import cut_depth, reduce_for_smoke
+
+    cfg = get_config(args.arch)
+    if args.size == "smoke":
+        return reduce_for_smoke(cfg)
+    return cut_depth(cfg, args.layers) if args.layers else cfg
+
+
+def _has_residency_manager(cfg, args: argparse.Namespace) -> bool:
+    """Whether the engine keeps its expert warehouse in host memory behind a
+    residency manager: the rotary engine always, the batch engine under a
+    rotating residency."""
+    return cfg.has_moe and (args.engine == "rotary" or args.residency != "full")
+
+
+def init_model(cfg, args: argparse.Namespace):
+    """Seeded params; experts go straight to host memory whenever the engine
+    keeps its expert warehouse there."""
     from repro.models import init_params
+
+    return init_params(cfg, jax.random.PRNGKey(args.seed),
+                       experts_on_host=_has_residency_manager(cfg, args))
+
+
+def build_engine(args: argparse.Namespace, cfg, params, tracer=None):
+    """The engine the flags describe, over ``params`` (from ``init_model``)."""
+    from repro.config import ResidencyConfig
     from repro.models.transformer import Runtime
     from repro.serving import SamplerConfig, ServingEngine
 
-    cfg = reduce_for_smoke(get_config(args.arch))
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
-    tracer = None
-    if args.trace_out:
-        from repro.obs import Tracer
-        tracer = Tracer()
     rt = Runtime(cache_len=args.cache_len)
-    rng = np.random.default_rng(args.seed)
     slots = args.slots or (cfg.moe.num_experts * 3 // 4 if cfg.has_moe else 0)
     rescfg = None
-    if args.residency != "full" and cfg.has_moe:
+    if _has_residency_manager(cfg, args):
         rescfg = ResidencyConfig(mode=args.residency, num_slots=slots,
                                  quantization=QUANT_CHOICES[args.quantization],
                                  quant_group_size=args.quant_group)
-
     if args.engine == "rotary":
         from repro.core import RotaryEngine
 
         assert cfg.has_moe, "--engine rotary requires an MoE arch"
-        b = max(1, args.batch)
-        eng = RotaryEngine(
-            cfg, params,
-            rescfg or ResidencyConfig(
-                mode="rotary", num_slots=slots,
-                quantization=QUANT_CHOICES[args.quantization],
-                quant_group_size=args.quant_group,
-            ),
-            rt=rt, batch=b, host_routing=args.host_routing,
+        return RotaryEngine(
+            cfg, params, rescfg,
+            rt=rt, batch=max(1, args.batch), host_routing=args.host_routing,
             spec_k=max(1, args.spec_k),
             prefill_chunk=args.prefill_chunk or None,
             prefetch=args.prefetch,
             trace=tracer,
         )
+    return ServingEngine(
+        cfg, params, rt=rt, num_slots=args.batch_slots, residency=rescfg,
+        sampler=SamplerConfig(
+            temperature=args.temperature, top_k=args.top_k, top_p=args.top_p,
+            seed=args.sample_seed if args.sample_seed is not None
+            else args.seed,
+        ),
+        spec_cap=max(1, args.spec_cap),
+        kv_page_size=args.kv_page_size,
+        kv_pages=args.kv_pages or None,
+        prefetch=args.prefetch,
+        trace=tracer,
+    )
+
+
+def _measured(summary: dict) -> dict:
+    """Stats as measured: the modeled clock's fields are not results."""
+    return {k: v for k, v in summary.items() if not k.startswith("modeled_")}
+
+
+def main(argv=None) -> None:
+    from repro.launch.compile_cache import place_compile_cache
+    from repro.serving import SamplerConfig
+
+    args = build_parser().parse_args(argv)
+    place_compile_cache()
+    cfg = model_config(args)
+    params = init_model(cfg, args)
+    tracer = None
+    if args.trace_out:
+        from repro.obs import Tracer
+        tracer = Tracer()
+    rng = np.random.default_rng(args.seed)
+    eng = build_engine(args, cfg, params, tracer)
+    del params          # the engine holds what it needs
+
+    if args.engine == "rotary":
+        b = eng.batch
         gen_kw = {}
         if args.temperature > 0:
             gen_kw = dict(greedy=False, sampler=SamplerConfig(
@@ -168,7 +233,7 @@ def main() -> None:
             out = eng.generate(prompt, args.max_new, **gen_kw)
             for i in range(n):
                 print(f"req {g0 + i}: {out[i].tolist()}")
-        print("stats:", eng.stats.summary())
+        print("stats:", _measured(eng.stats.summary()))
         print("per-layer residency:")
         print(eng.stats.per_layer_table())
         if tracer is not None:
@@ -176,19 +241,6 @@ def main() -> None:
             print(f"trace: {len(tracer)} events -> {args.trace_out}")
         return
 
-    eng = ServingEngine(
-        cfg, params, rt=rt, num_slots=args.batch_slots, residency=rescfg,
-        sampler=SamplerConfig(
-            temperature=args.temperature, top_k=args.top_k, top_p=args.top_p,
-            seed=args.sample_seed if args.sample_seed is not None
-            else args.seed,
-        ),
-        spec_cap=max(1, args.spec_cap),
-        kv_page_size=args.kv_page_size,
-        kv_pages=args.kv_pages or None,
-        prefetch=args.prefetch,
-        trace=tracer,
-    )
     metrics_server = None
     if args.metrics_port:
         from repro.obs import serve_metrics
@@ -224,7 +276,7 @@ def main() -> None:
         done = eng.run()
     for r in done:
         print(f"req {r.uid}: prompt_len={len(r.prompt)} -> {r.output}")
-    print("stats:", eng.summary())
+    print("stats:", _measured(eng.summary()))
     if metrics_server is not None:
         # self-scrape once so CI can assert the exposition round-trips
         from urllib.request import urlopen

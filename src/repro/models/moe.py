@@ -42,15 +42,16 @@ def init_moe(key: jax.Array, d_model: int, mcfg: MoEConfig, mlp_kind: str, dtype
     # dummies when the expert count doesn't divide the EP axis)
     e, f = mcfg.storage_experts, mcfg.expert_d_ff
     p: Params = {"router": dense_init(kr, (d_model, mcfg.num_experts), jnp.float32)}
+    # fan-in is each expert's own reduction dim, never the leading expert axis
     if mlp_kind == "swiglu":
         p["experts"] = {
-            "w_gate": dense_init(kg, (e, d_model, f), dtype),
-            "w_up": dense_init(ku, (e, d_model, f), dtype),
+            "w_gate": dense_init(kg, (e, d_model, f), dtype, fan_in=d_model),
+            "w_up": dense_init(ku, (e, d_model, f), dtype, fan_in=d_model),
             "w_down": dense_init(kd, (e, f, d_model), dtype, fan_in=f),
         }
     else:
         p["experts"] = {
-            "w_up": dense_init(ku, (e, d_model, f), dtype),
+            "w_up": dense_init(ku, (e, d_model, f), dtype, fan_in=d_model),
             "w_down": dense_init(kd, (e, f, d_model), dtype, fan_in=f),
         }
     if mcfg.num_shared_experts > 0:

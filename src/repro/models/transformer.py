@@ -16,10 +16,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import get_abstract_mesh, manual_axis_names, shard_map
+import numpy as np
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import get_abstract_mesh
 from repro.config.base import ModelConfig, ShardingConfig
+from repro.kernels.ops import route_topk
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import sampling as sampling_mod
@@ -62,7 +64,8 @@ class Runtime:
 
 
 def _manual_axes(am) -> set:
-    return manual_axis_names(am)
+    """Names of mesh axes that are Manual in the ambient shard_map context."""
+    return {n for n, t in zip(am.axis_names, am.axis_types) if t == AxisType.Manual}
 
 
 def _strip_manual(mesh, spec: P):
@@ -126,7 +129,40 @@ def _init_block(key: jax.Array, kind: str, cfg: ModelConfig, dtype: Any) -> Para
     raise ValueError(f"unknown block kind {kind!r}")
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+def _init_unit_host_experts(
+    pkeys: jax.Array, kind: str, cfg: ModelConfig, dtype: Any
+) -> Params:
+    """One ``attn_moe`` unit's stacked params with the routed experts in host
+    memory: each layer is drawn on the device from its own key, its experts
+    are copied into host arrays [reps, E, ...] of ``dtype`` and dropped from
+    the device before the next layer is drawn."""
+    reps = pkeys.shape[0]
+    rest: List[Params] = []
+    experts: Dict[str, np.ndarray] = {}
+    for r in range(reps):
+        p = _init_block(pkeys[r], kind, cfg, dtype)
+        moe = dict(p["moe"])
+        for n, w in moe.pop("experts").items():
+            if n not in experts:
+                experts[n] = np.empty((reps,) + w.shape, w.dtype)
+            experts[n][r] = np.asarray(w)
+            w.delete()
+        rest.append({**p, "moe": moe})
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *rest)
+    stacked["moe"]["experts"] = experts
+    return stacked
+
+
+def init_params(
+    cfg: ModelConfig, key: jax.Array, *, experts_on_host: bool = False
+) -> Params:
+    """Seeded parameters, stacked per segment.
+
+    ``experts_on_host`` keeps every MoE layer's routed-expert store out of
+    device memory: the experts come back as host numpy arrays in
+    ``cfg.dtype`` (same values and [reps, E, ...] layout as the device init),
+    for engines whose residency manager holds the expert warehouse on the
+    host and uploads slots from it."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, len(cfg.segments) + 3)
     segments: List[Tuple[Params, ...]] = []
@@ -134,7 +170,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         unit_params: List[Params] = []
         for pi, kind in enumerate(unit):
             pkeys = jax.random.split(jax.random.fold_in(keys[si], pi), reps)
-            stacked = jax.vmap(lambda k: _init_block(k, kind, cfg, dtype))(pkeys)
+            if experts_on_host and kind == "attn_moe":
+                stacked = _init_unit_host_experts(pkeys, kind, cfg, dtype)
+            else:
+                stacked = jax.vmap(
+                    lambda k: _init_block(k, kind, cfg, dtype)
+                )(pkeys)
             unit_params.append(stacked)
         segments.append(tuple(unit_params))
     p: Params = {
@@ -278,13 +319,17 @@ def _apply_block(
                     slot_buffer, lut = residency["slots"], residency["lut"]
                 h2d = h.reshape(-1, d)
                 logits = moe_mod.router_logits(p["moe"], h2d)
-                ids, weights, moe_aux = moe_mod.topk_route(logits, cfg.moe)
+                # the fused Pallas gate on TPU (lax.top_k elsewhere)
+                ids, weights = route_topk(
+                    logits, cfg.moe.top_k, normalize=cfg.moe.norm_topk_prob
+                )
+                moe_aux = {}
                 if (mode == "decode" and residency is None and rt.mesh is not None
                         and rt.sharding.moe_impl == "epsum"):
                     # §Perf: EP decode — local experts only + one [T,D] psum,
                     # instead of all-gathering the expert store per layer
                     am = get_abstract_mesh()
-                    mesh_arg = am if (am is not None and am.axis_names) else rt.mesh
+                    mesh_arg = am if am.axis_names else rt.mesh
                     manual = _manual_axes(am)
                     dp_eff = tuple(a for a in rt.dp_spec if a not in manual) or None
 
@@ -337,7 +382,7 @@ def _apply_block(
                     # mesh is rejected and manual axes may not be mentioned —
                     # use the ambient abstract mesh and strip manual axes
                     am = get_abstract_mesh()
-                    mesh_arg = am if (am is not None and am.axis_names) else rt.mesh
+                    mesh_arg = am if am.axis_names else rt.mesh
                     manual = _manual_axes(am)
                     dp_eff = tuple(a for a in rt.dp_spec if a not in manual) or None
                     fn = shard_map(
@@ -406,7 +451,7 @@ def _sp_attention(
     tp_size = dict(rt.mesh.shape)[tp]
     q, k, v = attn._project_qkv(p, acfg, h, jnp.arange(s)[None, :])
     am = get_abstract_mesh()
-    mesh_arg = am if (am is not None and am.axis_names) else rt.mesh
+    mesh_arg = am if am.axis_names else rt.mesh
     manual = _manual_axes(am)
     dp_eff = tuple(a for a in rt.dp_spec if a not in manual) or None
     s_loc = s // tp_size

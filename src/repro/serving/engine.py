@@ -44,7 +44,7 @@ accepted counts masking pad rows and rejected suffixes.
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,9 +53,12 @@ import numpy as np
 from repro.config.base import ModelConfig, ResidencyConfig
 from repro.core.engine import (
     build_fused_decode_step,
+    build_fused_prefill_step,
     build_window_fns,
     concat_route_telemetry,
     moe_segments,
+    prefill_chunk_plan,
+    split_expert_store,
 )
 from repro.core.predictor import DemandPredictor
 from repro.core.residency import RotaryResidencyManager
@@ -212,20 +215,15 @@ class ServingEngine:
         self.res_mgr: Optional[RotaryResidencyManager] = None
         self.predictor: Optional[DemandPredictor] = None
         if residency is not None and residency.mode != "full" and cfg.has_moe:
-            host_experts, routers = [], []
-            for si, (unit, reps) in enumerate(cfg.segments):
-                for r in range(reps):
-                    for pi, kind in enumerate(unit):
-                        if kind != "attn_moe":
-                            continue
-                        p_l = jax.tree.map(
-                            lambda a, r=r: a[r], params["segments"][si][pi]
-                        )
-                        host_experts.append(
-                            {n: np.asarray(w, np.float32)
-                             for n, w in p_l["moe"]["experts"].items()}
-                        )
-                        routers.append(np.asarray(p_l["moe"]["router"], np.float32))
+            if not kv_only:
+                raise ValueError(
+                    "a rotating residency needs a KV-cache-only stack: exact "
+                    "admission prefill reruns missed chunks over the same "
+                    f"cache positions ({cfg.layer_kinds})"
+                )
+            # the expert warehouse stays in host memory; compiled programs
+            # (admission prefill included) read experts through the slots
+            self.params, host_experts, routers = split_expert_store(cfg, params)
             # feasibility prices KV bytes: the pool holds pages-worth of KV,
             # not num_slots full rows, so report the pool-equivalent batch
             batch_eff = self.batch
@@ -241,6 +239,17 @@ class ServingEngine:
             self.predictor = DemandPredictor(routers, ema=residency.predictor_ema)
             for li in range(len(host_experts)):
                 self.res_mgr.prepare_layer(li, self.predictor.smoothed[li])
+            # admission chunk: the largest power of two whose routed set
+            # (chunk * top_k experts at most) fits the smallest slot count
+            slots = min(p.lut.num_slots for p in self.res_mgr.policies)
+            fit = slots // cfg.moe.top_k
+            if fit < 1:
+                raise ValueError(
+                    f"num_slots {slots} < top_k {cfg.moe.top_k}: one token's "
+                    "routed experts cannot all be resident, so admission "
+                    "prefill cannot be exact"
+                )
+            self._resident_chunk = 1 << (fit.bit_length() - 1)
 
         # --- compiled steps ---------------------------------------------
         # ticks share the rotary engine's fused whole-stack programs: KV state
@@ -287,6 +296,7 @@ class ServingEngine:
         self._moe_segs = moe_segments(cfg)
         self._prefill_cache: Dict[int, Any] = {}
         self._bucket_prefill_cache: Dict[int, Any] = {}
+        self._resident_prefill_cache: Dict[Tuple[int, bool], Any] = {}
         self._window_cache: Dict[int, Any] = {}
         self._paged_splice_cache: Dict[int, Any] = {}
         self._has_recurrence = any(
@@ -324,6 +334,77 @@ class ServingEngine:
         return key
 
     # ------------------------------------------------------------------
+    def _resident_step(self, c: int, with_head: bool) -> Callable:
+        """Compiled ``c``-token prefill chunk through the residency slots
+        (state NOT donated: a chunk that misses runs again from it)."""
+        key = (c, with_head)
+        fn = self._resident_prefill_cache.get(key)
+        if fn is None:
+            fn = build_fused_prefill_step(
+                self.cfg, self.rt, with_demand=False, donate_state=False,
+                with_head=with_head,
+            )
+            self._resident_prefill_cache[key] = fn
+        return fn
+
+    def _prefill_resident(self, prompt: np.ndarray) -> Tuple[np.ndarray, Any]:
+        """Exact batch-1 admission prefill with the expert store in host
+        memory: the prompt ingests in chunks of at most ``_resident_chunk``
+        tokens, small enough that a chunk's routed set fits every layer's
+        slots. A launch that reports a miss uploads its routed experts
+        (``ensure_resident``) and runs the chunk again from the same state,
+        until a launch is miss-free. Layers before the first missed one saw
+        the same inputs, so each relaunch clears at least that layer: a chunk
+        takes at most one launch per MoE layer plus one, and its logits and
+        KV are what the full expert store computes. Each MoE layer's routing
+        is recorded once per chunk, from the first launch whose input to it
+        was exact. Returns (logits [1, V], row state)."""
+        mgr = self.res_mgr
+        n_moe = len(mgr.policies)
+        plan = prefill_chunk_plan(len(prompt), self._resident_chunk)
+        cold = any(
+            (c, i == len(plan) - 1) not in self._resident_prefill_cache
+            for i, c in enumerate(plan)
+        )
+        t0 = time.perf_counter()
+        state = tfm.zero_state(self.cfg, 1, self.rt.cache_len)
+        pos = 0
+        for i, c in enumerate(plan):
+            step = self._resident_step(c, i == len(plan) - 1)
+            tokens = jnp.asarray(prompt[None, pos : pos + c])
+            done = 0                  # MoE layers whose routing is recorded
+            while True:
+                logits, new_state, aux = step(
+                    self.params, None, tokens, state, jnp.int32(pos),
+                    mgr.stacked_residency(),
+                )
+                miss = concat_route_telemetry(aux, "miss", self._moe_segs)
+                ids = concat_route_telemetry(aux, "ids", self._moe_segs)
+                missed = np.flatnonzero(miss.reshape(n_moe, -1).any(axis=1))
+                first = int(missed[0]) if missed.size else n_moe
+                for li in range(done, min(first + 1, n_moe)):
+                    mgr.record_routing(li, ids[li], miss[li])
+                if not missed.size:
+                    break
+                for li in missed:
+                    routed = np.unique(ids[li])
+                    if mgr.ensure_resident(int(li), routed, routed) is None:
+                        raise RuntimeError(
+                            f"MoE layer {li}: {routed.size} routed experts "
+                            f"exceed its slots; admission prefill cannot "
+                            f"be made exact"
+                        )
+                done = first + 1
+                self.stats.relaunched_steps += 1
+            state = new_state
+            pos += c
+            self.stats.prefill_chunks += 1
+        logits = np.asarray(logits)
+        dt = time.perf_counter() - t0
+        if not cold and dt > 0:
+            self.scheduler.observe_prefill_rate(len(prompt) / dt)
+        return logits, state
+
     def _prefill_one(self, prompt: np.ndarray) -> Any:
         """Batch-1 prefill at a power-of-two length bucket (right-padded;
         decode masks cache positions >= true length so pads never score).
@@ -411,10 +492,14 @@ class ServingEngine:
         return out
 
     def _prefill_admitted(self, admitted: List[Request]) -> List[Any]:
-        """Admission prefill: the shared bucketed program by default, batch-1
+        """Admission prefill: the exact slot-chunked path under a rotating
+        residency, else the shared bucketed program by default, batch-1
         programs for recurrent archs / ``bucketed_prefill=False``."""
         if not admitted:
             return []
+        if self.res_mgr is not None:
+            return [(req, *self._prefill_resident(req.prompt))
+                    for req in admitted]
         if self._bucketed_prefill:
             return self._prefill_bucketed(admitted)
         out = []
@@ -499,10 +584,29 @@ class ServingEngine:
         stats. Returns the number of programs compiled."""
         compiled = 0
         mp = max(1, min(max_prompt_len, self.rt.cache_len))
-        # admission prefill: every power-of-two bucket the envelope reaches,
-        # at every power-of-two admission group size (recurrent archs prefill
-        # at exact lengths — nothing reusable to pre-compile)
-        if not self._has_recurrence:
+        # admission prefill: under a rotating residency every (chunk, head)
+        # shape the envelope's chunk plans reach; else every power-of-two
+        # bucket it reaches, at every power-of-two admission group size
+        # (recurrent archs prefill at exact lengths — nothing reusable to
+        # pre-compile)
+        if self.res_mgr is not None:
+            shapes = {
+                (c, i == len(plan) - 1)
+                for l in range(1, mp + 1)
+                for plan in [prefill_chunk_plan(l, self._resident_chunk)]
+                for i, c in enumerate(plan)
+            }
+            residency = self.res_mgr.stacked_residency()
+            for c, head in sorted(shapes):
+                if (c, head) not in self._resident_prefill_cache:
+                    out = self._resident_step(c, head)(
+                        self.params, None, jnp.zeros((1, c), jnp.int32),
+                        tfm.zero_state(self.cfg, 1, self.rt.cache_len),
+                        jnp.int32(0), residency,
+                    )
+                    jax.block_until_ready(out[1])
+                    compiled += 1
+        elif not self._has_recurrence:
             buckets = sorted({
                 Scheduler.prefill_bucket([l], self.rt.cache_len)
                 for l in range(1, mp + 1)

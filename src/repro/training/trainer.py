@@ -15,7 +15,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.config.base import ModelConfig, RunConfig
 from repro.models.transformer import Runtime, lm_loss
 from repro.training.optimizer import adamw_init, adamw_update

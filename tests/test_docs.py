@@ -112,7 +112,8 @@ def test_serve_cli_flags_exist():
     """The CLI flags the docs/Makefile reference parse (smoke the argparse
     wiring without running a model)."""
     serve_src = _read("src/repro/launch/serve.py")
-    for flag in ("--prefill-chunk", "--spec-k", "--spec-cap",
+    for flag in ("--size", "--layers",
+                 "--prefill-chunk", "--spec-k", "--spec-cap",
                  "--quantization", "--quant-group",
                  "--arrival-rate", "--kv-pages", "--kv-page-size",
                  "--prefetch", "--trace-out", "--metrics-port",
@@ -130,3 +131,23 @@ def test_serve_cli_flags_exist():
     assert "--temperature 0.8" in makefile        # smoke-sample really samples
     assert "--sample-seed" in makefile            # ... with a pinned seed
     assert "accept_rate" in makefile              # ... and asserts telemetry
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes to
+    the checkout's fixed .jax_cache. (config.update is intercepted: tests
+    never turn the persistent cache on.)"""
+    import jax
+
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.place_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.place_compile_cache()
+    assert path == str(ROOT / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert ".jax_cache/" in _read(".gitignore")

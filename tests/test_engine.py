@@ -59,16 +59,22 @@ def test_rotary_prefetch_beats_lru_on_bytes(rng):
 
 
 def test_residency_restricts_device_params():
-    """With rotary residency, the device layer params must NOT contain the
-    full expert store (the warehouse stays in host memory)."""
+    """The device layer params never contain the expert store (the warehouse
+    stays in host memory, in the model dtype); full residency holds every
+    expert in its slot stores instead."""
     cfg, eng = _engine("qwen36-35b-a3b", "rotary", 5)
     for kind, p_l in eng.layers:
         if kind == "attn_moe":
             assert "experts" not in p_l["moe"]
+    for hw in eng.host_experts:
+        for w in hw.values():
+            assert isinstance(w, np.ndarray) and w.dtype == jnp.dtype(cfg.dtype)
     cfg2, eng_full = _engine("qwen36-35b-a3b", "full", 0)
     for kind, p_l in eng_full.layers:
         if kind == "attn_moe":
-            assert "experts" in p_l["moe"]
+            assert "experts" not in p_l["moe"]
+    for store in eng_full.manager.stores:
+        assert store.num_slots == cfg2.moe.num_experts
 
 
 def test_stats_accounting(rng):

@@ -123,7 +123,7 @@ def test_routing_parity_on_ties(rng):
     index wins) — residency accounting depends on the three agreeing."""
     from repro.config import MoEConfig
     from repro.core.predictor import host_topk_route
-    from repro.kernels.topk_gate import route_topk
+    from repro.kernels.ops import route_topk
     from repro.models import moe as M
 
     t, e, k = 8, 16, 4
@@ -147,3 +147,12 @@ def test_routing_parity_on_ties(rng):
     np.testing.assert_array_equal(ids_host, np.asarray(ids_model))
     np.testing.assert_allclose(w_host, np.asarray(w_auto), atol=1e-6)
     np.testing.assert_allclose(w_host, np.asarray(w_pal), atol=1e-6)
+
+
+def test_kernels_interpret_only_on_cpu(monkeypatch, rng):
+    """Pallas kernels interpret on the CPU backend and lower on TPU; any other
+    backend is an error, never a silent interpreter run."""
+    logits = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.topk_gate(logits, 2)

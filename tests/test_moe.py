@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.config import MoEConfig
 from repro.models import moe as M
 
@@ -91,8 +91,10 @@ def test_slot_lut_miss_drops_expert(rng):
     hw = {n: np.asarray(p["experts"][n]) for n in p["experts"]}
     corr = np.zeros_like(np.asarray(y))
     for t, j in zip(*np.nonzero(np.asarray(miss))):
-        corr[t] += w_missed[t, j] * _np_ffn(hw, int(np.asarray(ids)[t, j]),
-                                            np.asarray(x2d)[t])
+        e = int(np.asarray(ids)[t, j])
+        corr[t] += w_missed[t, j] * _np_ffn(
+            {n: w[e] for n, w in hw.items()}, np.asarray(x2d)[t]
+        )
     np.testing.assert_allclose(np.asarray(y) + corr, np.asarray(y_full),
                                atol=2e-3)
 

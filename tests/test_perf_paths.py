@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.config import AttentionConfig, MoEConfig, ShardingConfig
 from repro.models import attention as A
 from repro.models import moe as M

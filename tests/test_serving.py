@@ -73,6 +73,9 @@ def test_serving_rotary_residency_runs(rng):
     assert len(done) == 3
     assert all(len(r.output) == 4 for r in done)
     assert eng.stats.hits + eng.stats.misses > 0
+    # the expert store stays in host memory: no compiled program (admission
+    # prefill included) receives it
+    assert "experts" not in eng.params["segments"][0][0]["moe"]
 
 
 # ===========================================================================

@@ -160,6 +160,15 @@ def test_kv_pool_ensure_past_reservation_raises():
 # ===========================================================================
 # continuous batching exactness (the PR contract)
 # ===========================================================================
+def _f32_params(arch):
+    import dataclasses
+
+    from repro.models import init_params
+
+    cfg = dataclasses.replace(params_for(arch)[0], dtype="float32")
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
+
+
 def _serve(cfg, params, prompts, *, num_slots, max_new=5, cache_len=32,
            rescfg=None, spec_cap=4, seeds=None, **kw):
     eng = ServingEngine(
@@ -207,10 +216,11 @@ def test_cb_sampled_matches_isolated(rng, regime):
     including through speculative windows whose rejected drafts re-draw the
     same positions with the same fold_in keys. Scoped to the f32 miss-free
     regimes: int4 dequant differs sub-ULP across row-bucket batch shapes,
-    which greedy argmax absorbs but a categorical draw can flip."""
+    which greedy argmax absorbs but a categorical draw can flip — and in
+    float32, since bf16 logits can differ by an ULP across row buckets."""
     from repro.serving.sampler import SamplerConfig
 
-    cfg, params = params_for("qwen2-moe-a2.7b")
+    cfg, params = _f32_params("qwen2-moe-a2.7b")
     e = cfg.moe.num_experts
     mk_res = lambda: (None if regime == "full" else
                       ResidencyConfig(mode="rotary", num_slots=e))
@@ -284,6 +294,32 @@ def test_cb_slot_starved_concurrent_completes(rng):
     assert eng.stats.accepted_tokens <= eng.stats.drafted_tokens
     s = eng.stats
     assert s.kv_pages_released == s.kv_pages_allocated > 0
+
+
+def test_slot_starved_admission_prefill_is_exact(rng):
+    """Admission prefill under a rotary residency at its minimum slot count
+    (the expert store stays in host memory) reruns each missed chunk until it
+    is miss-free, so its logits and prompt KV equal the full-store prefill's
+    in f32 — and the misses are counted."""
+    from repro.serving.scheduler import Request
+
+    cfg, params = _f32_params("qwen2-moe-a2.7b")
+    s = 11
+    prompt = rng.integers(0, cfg.vocab_size, s).astype(np.int32)
+    full = ServingEngine(cfg, params, rt=Runtime(cache_len=32), num_slots=1)
+    starved = ServingEngine(
+        cfg, params, rt=Runtime(cache_len=32), num_slots=1,
+        residency=ResidencyConfig(mode="rotary", num_slots=2 * cfg.moe.top_k),
+    )
+    [(_, lg_ref, st_ref)] = full._prefill_admitted([Request(0, prompt, 1)])
+    [(_, lg, st)] = starved._prefill_admitted([Request(0, prompt, 1)])
+    np.testing.assert_allclose(lg, np.asarray(lg_ref), rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(st_ref)):
+        np.testing.assert_allclose(np.asarray(a)[:, :, :s],
+                                   np.asarray(b)[:, :, :s],
+                                   rtol=1e-5, atol=1e-5)
+    assert starved.stats.misses > 0 and starved.stats.relaunched_steps > 0
+    assert starved.stats.prefill_chunks == 6        # 2-token chunks: 5 + 1
 
 
 def test_cb_page_recycling_under_queueing_exact(rng):
@@ -383,6 +419,25 @@ def test_warmup_precompiles_without_changing_outputs(rng):
     assert [r.output for r in reqs] == ref
 
 
+def test_warmup_precompiles_resident_prefill(rng):
+    """Under a rotating residency warmup compiles every admission chunk shape
+    the envelope reaches, so serving then compiles no prefill program, and
+    the outputs match an engine that was not warmed."""
+    cfg, params = params_for("qwen2-moe-a2.7b")
+    res = lambda: ResidencyConfig(mode="rotary", num_slots=5)
+    prompts = [rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32)
+               for n in (5, 9)]
+    eng = ServingEngine(cfg, params, rt=Runtime(cache_len=32), num_slots=2,
+                        residency=res(), spec_cap=4)
+    assert eng.warmup(max_prompt_len=9) > 0
+    shapes = set(eng._resident_prefill_cache)
+    reqs = [eng.submit(p, max_new=5) for p in prompts]
+    eng.run()
+    assert set(eng._resident_prefill_cache) == shapes
+    _, ref = _serve(cfg, params, prompts, num_slots=2, rescfg=res())
+    assert [r.output for r in reqs] == ref
+
+
 # ===========================================================================
 # asynchronous prefetch on the CB tick: shadow generations over the pool
 # ===========================================================================
@@ -392,13 +447,7 @@ def test_cb_prefetch_matches_sync(rng):
     bit-identical tokens to the synchronous-rotation engine on the same
     trace — prefetch-covered AND slot-starved f32 (host corrections are
     bitwise against device compute at f32)."""
-    import dataclasses
-
-    from repro.models import init_params
-
-    cfg, _ = params_for("qwen2-moe-a2.7b")
-    cfg = dataclasses.replace(cfg, dtype="float32")
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    cfg, params = _f32_params("qwen2-moe-a2.7b")
     e = cfg.moe.num_experts
     prompts = [rng.integers(0, cfg.vocab_size, int(n)).astype(np.int32)
                for n in (5, 9, 7)]

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from conftest import params_for
-from repro.compat import shard_map
+from jax import shard_map
 from repro.config import RunConfig
 from repro.data import SyntheticSpec, batch_at_step
 from repro.models.transformer import Runtime

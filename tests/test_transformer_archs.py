@@ -84,3 +84,40 @@ def test_decode_matches_teacher_forcing(arch, rng):
             **tol,
         )
         assert int(np.argmax(lg)) == int(np.argmax(logits_tf[:, t]))
+
+
+@pytest.mark.parametrize("arch", ["qwen2-moe-a2.7b", "qwen36-35b-a3b"])
+def test_init_experts_on_host_matches_device_init(arch):
+    """Experts drawn layer by layer into host memory are the device init's
+    values, as numpy arrays in the model dtype; nothing else moves."""
+    from repro.models import init_params
+
+    cfg, dev = params_for(arch)
+    host = init_params(cfg, jax.random.PRNGKey(0), experts_on_host=True)
+    leaves_d, tree_d = jax.tree.flatten(dev)
+    leaves_h, tree_h = jax.tree.flatten(host)
+    assert tree_d == tree_h
+    for a, b in zip(leaves_d, leaves_h):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for w in host["segments"][0][0]["moe"]["experts"].values():
+        assert isinstance(w, np.ndarray) and w.dtype == jnp.dtype(cfg.dtype)
+    assert isinstance(host["embed"], jax.Array)
+
+
+def test_cut_depth_keeps_published_widths():
+    from repro.config import get_config
+    from repro.configs import cut_depth
+
+    full = get_config("qwen36-35b-a3b")
+    cut = cut_depth(full, 4)
+    assert cut.num_layers == 4 and cut.name == "qwen36-35b-a3b-4L"
+    assert (cut.d_model, cut.vocab_size, cut.attention, cut.moe, cut.dtype) == (
+        full.d_model, full.vocab_size, full.attention, full.moe, full.dtype)
+    assert cut_depth(full, full.num_layers) is full
+    # multi-kind units are kept whole
+    rg = get_config("recurrentgemma-2b")
+    assert cut_depth(rg, 4).num_layers == 3
+    with pytest.raises(ValueError):
+        cut_depth(full, 0)
+    with pytest.raises(ValueError):
+        cut_depth(rg, 2)            # no whole 3-layer unit fits
