@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran on the chip,
+1 - (union of device-op intervals) / window, from the profiler trace."""
+
+
+def read(ctx):
+    if ctx.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / ctx.trace["window_s"])
