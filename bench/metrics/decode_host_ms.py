@@ -1,0 +1,7 @@
+"""Engine layer: mean host time of a fused decode step in the window, in ms:
+each ``step`` span of kind ``decode`` less the ``pull`` spans of its unit."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.host_ms(ctx.spans, "decode")

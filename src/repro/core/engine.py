@@ -131,8 +131,7 @@ from repro.models import sampling as sampling_mod
 from repro.models.sampling import SampleParams
 from repro.models.layers import apply_norm
 from repro.models.transformer import Runtime
-from repro.obs.metrics import MetricsRegistry
-from repro.obs.tracer import resolve_tracer
+from repro.obs.tracer import maybe_span, resolve_tracer
 
 
 def _np_ffn(w: Dict[str, np.ndarray], x: np.ndarray) -> np.ndarray:
@@ -488,10 +487,12 @@ class RotaryEngine:
           the exactness machinery (host correction + replay) is unchanged.
           Requires the fused hot path; ``prefetch=False`` (the default)
           keeps the synchronous rotation path as the exactness baseline.
-        * ``trace=Tracer(...)`` — record launch/pull/rotation/prefetch spans
-          into a host-side ring buffer and export Chrome trace-event JSON
-          (``repro.obs``). ``None`` (and a disabled tracer) leave every hot
-          path untouched: emission sites are guarded ``if tr is not None``.
+        * ``trace=Tracer(...)`` — record step/launch/pull/rotation/prefetch
+          spans into a host-side ring buffer and export Chrome trace-event
+          JSON (``repro.obs``); open spans also show on a JAX profiler
+          capture as ``engine/<name>``. ``None`` (and a disabled tracer)
+          keep no tracer reference: instants are guarded ``if tr is not
+          None`` and span sites enter the no-op ``NO_SPAN``.
         """
         assert cfg.has_moe, "RotaryEngine requires an MoE architecture"
         self.cfg = cfg
@@ -504,7 +505,6 @@ class RotaryEngine:
         self.clock = TransferClock(self.cost)
         self._tr = resolve_tracer(trace)
         self.tracer = self._tr
-        self.metrics = MetricsRegistry()
 
         # ---- flatten the layer stack; slice per-layer params -------------
         # the expert warehouse lives in host memory in EVERY residency mode
@@ -547,7 +547,7 @@ class RotaryEngine:
             cfg, rescfg, self.host_experts,
             batch=batch, cache_len=self.rt.cache_len,
             cost=self.cost, stats=self.stats, seed=seed,
-            tracer=self._tr, metrics=self.metrics,
+            tracer=self._tr,
         )
         # LRU answers misses with reactive blocking loads mid-step: that needs
         # routed ids on host before the next layer, i.e. the sync walk
@@ -1082,18 +1082,14 @@ class RotaryEngine:
         batched uploads). Returns host logits [B, V]; see module docstring."""
         cur_len = self.cur_len
         tr = self._tr
-        if tr is not None:
-            tr.new_unit("decode")
-            t_trace = time.perf_counter()
-        residency = self.manager.stacked_residency()
-        logits_dev, self._dstate, aux = self._fused_step(
-            self._decode_params, self._routers_next, jnp.asarray(tok),
-            self._dstate, jnp.int32(cur_len), residency,
-        )
-        self.stats.device_dispatches += 1
-        if tr is not None:
-            tr.complete("launch", "launch", t_trace, time.perf_counter(),
-                        args={"cur_len": cur_len})
+        with maybe_span(tr, "launch", args={"cur_len": cur_len}):
+            with maybe_span(tr, "view"):
+                residency = self.manager.stacked_residency()
+            logits_dev, self._dstate, aux = self._fused_step(
+                self._decode_params, self._routers_next, jnp.asarray(tok),
+                self._dstate, jnp.int32(cur_len), residency,
+            )
+            self.stats.device_dispatches += 1
         # async D2H: these complete while the logits pull below drains the
         # queue, so the rotation bookkeeping reads ready host buffers
         for k in self._pull_keys:
@@ -1105,17 +1101,14 @@ class RotaryEngine:
             # so this host work + the scatters overlap the device compute the
             # blocking pull below waits on
             self.manager.begin_prefetch(self.predictor, self.clock)
-        if tr is not None:
-            t_trace = time.perf_counter()
-        logits = np.asarray(logits_dev)        # THE one queue-draining pull
-        self.stats.sync_pulls += 1
-        if tr is not None:
-            tr.complete("pull", "pull", t_trace, time.perf_counter(),
-                        args={"cur_len": cur_len})
-        ids = concat_route_telemetry(aux, "ids", self._moe_segs)      # [L, T, k]
-        weights = concat_route_telemetry(aux, "weights", self._moe_segs)
-        miss = concat_route_telemetry(aux, "miss", self._moe_segs)
-        demand_next = np.asarray(aux["demand_next"])   # [L, E]
+        with maybe_span(tr, "pull", "pull", args={"cur_len": cur_len}):
+            logits = np.asarray(logits_dev)    # THE one queue-draining pull
+            self.stats.sync_pulls += 1
+        with maybe_span(tr, "telemetry", "pull"):
+            ids = concat_route_telemetry(aux, "ids", self._moe_segs)  # [L,T,k]
+            weights = concat_route_telemetry(aux, "weights", self._moe_segs)
+            miss = concat_route_telemetry(aux, "miss", self._moe_segs)
+            demand_next = np.asarray(aux["demand_next"])   # [L, E]
         missed = np.flatnonzero(miss.reshape(miss.shape[0], -1).any(axis=1))
         if tr is not None and missed.size:
             tr.instant("miss", "launch",
@@ -1134,7 +1127,8 @@ class RotaryEngine:
         # stats + modeled clock for the authoritative prefix in seed order
         # (layers before the first miss are exact as computed; the replay
         # charges the suffix itself)
-        self._account_step_prefix(ids, miss, start_li, cur_len)
+        with maybe_span(tr, "account"):
+            self._account_step_prefix(ids, miss, start_li, cur_len)
         if start_li < len(self.layers):
             # miss-relaunch (prefetch mode): upload the known-missed experts —
             # no prediction involved — let the incremental planes/LUT absorb
@@ -1242,9 +1236,8 @@ class RotaryEngine:
         """
         cur_len0 = self.cur_len
         tr = self._tr
-        if tr is not None:
-            tr.new_unit("window")
-        residency = self.manager.stacked_residency()
+        with maybe_span(tr, "view"):
+            residency = self.manager.stacked_residency()
         step_fn, snap_fn, roll_fn = self._window_fns(k, sample)
         saved = None
         if self._spec_needs_rollback:
@@ -1254,18 +1247,14 @@ class RotaryEngine:
             self.stats.device_dispatches += 1
             if tr is not None:
                 tr.instant("kv_snapshot", "launch", args={"k": k})
-        if tr is not None:
-            t_trace = time.perf_counter()
-        draft_dev, logits_dev, self._dstate, aux = step_fn(
-            self._decode_params, self._routers_next, jnp.asarray(tok),
-            self._dstate, jnp.int32(cur_len0), residency,
-            rng_keys=rng_keys,
-        )
-        self.stats.device_dispatches += 1
-        self.stats.spec_windows += 1
-        if tr is not None:
-            tr.complete("launch", "launch", t_trace, time.perf_counter(),
-                        args={"cur_len": cur_len0, "k": k})
+        with maybe_span(tr, "launch", args={"cur_len": cur_len0, "k": k}):
+            draft_dev, logits_dev, self._dstate, aux = step_fn(
+                self._decode_params, self._routers_next, jnp.asarray(tok),
+                self._dstate, jnp.int32(cur_len0), residency,
+                rng_keys=rng_keys,
+            )
+            self.stats.device_dispatches += 1
+            self.stats.spec_windows += 1
         pull_keys = self._pull_keys
         if sample is not None:
             pull_keys = pull_keys + ["sample_probs", "sample_p"]
@@ -1277,18 +1266,16 @@ class RotaryEngine:
             # whole window still in flight: shadow-upload the predicted next
             # transition under it (committed at the boundary rotation below)
             self.manager.begin_prefetch(self.predictor, self.clock)
-        if tr is not None:
-            t_trace = time.perf_counter()
-        logits = np.asarray(logits_dev)        # THE one queue-draining pull
-        self.stats.sync_pulls += 1
-        if tr is not None:
-            tr.complete("pull", "pull", t_trace, time.perf_counter(),
-                        args={"cur_len": cur_len0, "k": k})
-        draft = np.asarray(draft_dev)                               # [K, B]
-        ids = concat_route_telemetry(aux, "ids", self._moe_segs, axis=1)
-        weights = concat_route_telemetry(aux, "weights", self._moe_segs, axis=1)
-        miss = concat_route_telemetry(aux, "miss", self._moe_segs, axis=1)
-        demand_next = np.asarray(aux["demand_next"])                # [K, L, E]
+        with maybe_span(tr, "pull", "pull", args={"cur_len": cur_len0, "k": k}):
+            logits = np.asarray(logits_dev)    # THE one queue-draining pull
+            self.stats.sync_pulls += 1
+        with maybe_span(tr, "telemetry", "pull"):
+            draft = np.asarray(draft_dev)                           # [K, B]
+            ids = concat_route_telemetry(aux, "ids", self._moe_segs, axis=1)
+            weights = concat_route_telemetry(aux, "weights", self._moe_segs,
+                                             axis=1)
+            miss = concat_route_telemetry(aux, "miss", self._moe_segs, axis=1)
+            demand_next = np.asarray(aux["demand_next"])            # [K, L, E]
         # --- accept rule ------------------------------------------------
         # self-draft with identical weights: greedy verification argmaxes ARE
         # the drafted tokens, and the stochastic rule sees draft dist ==
@@ -1338,10 +1325,11 @@ class RotaryEngine:
         self.stats.drafted_tokens += k
         self.stats.accepted_tokens += accept
         # --- stats + modeled clock for fully-accepted positions ---------
-        for s in range(accept):
-            self._account_step_prefix(
-                ids[s], miss[s], len(self.layers), cur_len0 + s
-            )
+        with maybe_span(tr, "account"):
+            for s in range(accept):
+                self._account_step_prefix(
+                    ids[s], miss[s], len(self.layers), cur_len0 + s
+                )
         committed = accept
         if j_star is not None:
             # reject the suffix: roll the KV cache back past position j*
@@ -1426,31 +1414,25 @@ class RotaryEngine:
             if moved:
                 self.clock.blocking(moved)
             tr = self._tr
-            if tr is not None:
-                t_trace = time.perf_counter()
-            residency = self.manager.stacked_residency()
-            logits_dev, self._dstate, aux = self._fused_step(
-                self._decode_params, self._routers_next, jnp.asarray(tok),
-                self._dstate, jnp.int32(cur_len), residency,
-            )
-            self.stats.device_dispatches += 1
-            self.stats.relaunched_steps += 1
-            if tr is not None:
-                tr.complete("launch", "launch", t_trace, time.perf_counter(),
-                            args={"kind": "relaunch"})
+            with maybe_span(tr, "launch", args={"kind": "relaunch"}):
+                with maybe_span(tr, "view"):
+                    residency = self.manager.stacked_residency()
+                logits_dev, self._dstate, aux = self._fused_step(
+                    self._decode_params, self._routers_next, jnp.asarray(tok),
+                    self._dstate, jnp.int32(cur_len), residency,
+                )
+                self.stats.device_dispatches += 1
+                self.stats.relaunched_steps += 1
             for k in self._pull_keys:
                 aux[k].copy_to_host_async()
-            if tr is not None:
-                t_trace = time.perf_counter()
-            logits = np.asarray(logits_dev)
-            self.stats.sync_pulls += 1
-            if tr is not None:
-                tr.complete("pull", "pull", t_trace, time.perf_counter(),
-                            args={"kind": "relaunch"})
-            ids = concat_route_telemetry(aux, "ids", self._moe_segs)
-            weights = concat_route_telemetry(aux, "weights", self._moe_segs)
-            miss = concat_route_telemetry(aux, "miss", self._moe_segs)
-            demand_next = np.asarray(aux["demand_next"])
+            with maybe_span(tr, "pull", "pull", args={"kind": "relaunch"}):
+                logits = np.asarray(logits_dev)
+                self.stats.sync_pulls += 1
+            with maybe_span(tr, "telemetry", "pull"):
+                ids = concat_route_telemetry(aux, "ids", self._moe_segs)
+                weights = concat_route_telemetry(aux, "weights", self._moe_segs)
+                miss = concat_route_telemetry(aux, "miss", self._moe_segs)
+                demand_next = np.asarray(aux["demand_next"])
             if not miss.any():
                 # suffix accounting: the caller charged layers < start_li from
                 # the original launch; the relaunch is authoritative for the
@@ -1509,37 +1491,32 @@ class RotaryEngine:
             if moved:
                 self.clock.blocking(moved)
             tr = self._tr
-            if tr is not None:
-                t_trace = time.perf_counter()
-            residency = self.manager.stacked_residency()
-            draft_dev, logits_dev, self._dstate, aux = step_fn(
-                self._decode_params, self._routers_next, jnp.asarray(tok),
-                self._dstate, jnp.int32(cur_len0), residency,
-                rng_keys=rng_keys,
-            )
-            self.stats.device_dispatches += 1
-            self.stats.relaunched_steps += 1
-            if tr is not None:
-                tr.complete("launch", "launch", t_trace, time.perf_counter(),
-                            args={"kind": "relaunch"})
+            with maybe_span(tr, "launch", args={"kind": "relaunch"}):
+                with maybe_span(tr, "view"):
+                    residency = self.manager.stacked_residency()
+                draft_dev, logits_dev, self._dstate, aux = step_fn(
+                    self._decode_params, self._routers_next, jnp.asarray(tok),
+                    self._dstate, jnp.int32(cur_len0), residency,
+                    rng_keys=rng_keys,
+                )
+                self.stats.device_dispatches += 1
+                self.stats.relaunched_steps += 1
             pull_keys = self._pull_keys
             if sample is not None:
                 pull_keys = pull_keys + ["sample_probs", "sample_p"]
             for key in pull_keys:
                 aux[key].copy_to_host_async()
             draft_dev.copy_to_host_async()
-            if tr is not None:
-                t_trace = time.perf_counter()
-            logits = np.asarray(logits_dev)
-            self.stats.sync_pulls += 1
-            if tr is not None:
-                tr.complete("pull", "pull", t_trace, time.perf_counter(),
-                            args={"kind": "relaunch"})
-            draft = np.asarray(draft_dev)
-            ids = concat_route_telemetry(aux, "ids", self._moe_segs, axis=1)
-            weights = concat_route_telemetry(aux, "weights", self._moe_segs, axis=1)
-            miss = concat_route_telemetry(aux, "miss", self._moe_segs, axis=1)
-            demand_next = np.asarray(aux["demand_next"])
+            with maybe_span(tr, "pull", "pull", args={"kind": "relaunch"}):
+                logits = np.asarray(logits_dev)
+                self.stats.sync_pulls += 1
+            with maybe_span(tr, "telemetry", "pull"):
+                draft = np.asarray(draft_dev)
+                ids = concat_route_telemetry(aux, "ids", self._moe_segs, axis=1)
+                weights = concat_route_telemetry(aux, "weights", self._moe_segs,
+                                                 axis=1)
+                miss = concat_route_telemetry(aux, "miss", self._moe_segs, axis=1)
+                demand_next = np.asarray(aux["demand_next"])
             if not miss.any():
                 probs = (
                     np.asarray(aux["sample_probs"]) if sample is not None
@@ -1573,55 +1550,52 @@ class RotaryEngine:
         cache back past ``step`` BEFORE calling this, so the cache the suffix
         reads holds no writes from rejected positions.
         """
-        tr = self._tr
-        t_trace = time.perf_counter() if tr is not None else 0.0
-        si0, r0 = self._moe_pos[start_moe]
-        x_anchor = aux[f"route_x/seg{si0}"]
-        if step is not None:
-            x_anchor = x_anchor[step]
-        x = x_anchor[r0].reshape(self.batch, 1, -1)
-        self.stats.device_dispatches += 1             # device-side slice
-        cur = jnp.int32(cur_len)
-        clock = self.clock
-        for li in range(start_li, len(self.layers)):
-            kind, p_l = self.layers[li]
-            state = self._layer_state(li)
-            if kind == "attn_moe":
-                moe_li = self.moe_index[li]
-                attn_half, moe_half = self._block_fn(kind, "decode", routed=True)
-                x_mid, h2, ids_dev, w_dev, new_state = attn_half(p_l, x, state, cur)
-                slots_tree = self.manager.stores[moe_li].as_pytree()
-                lut_dev = self.manager.device_lut(moe_li)
-                x, miss_dev = moe_half(
-                    p_l, x_mid, h2, ids_dev, w_dev, slots_tree, lut_dev
-                )
-                self.stats.device_dispatches += 2
-                ids = np.asarray(ids_dev)
-                weights = np.asarray(w_dev)
-                miss = np.asarray(miss_dev)
-                self.stats.sync_pulls += 1
-                self.stats.replay_pulls += 1
-                self.manager.record_routing(moe_li, ids, miss)
-                if miss.any() and self.rescfg.host_compute_misses:
-                    x = self._host_correct(x, moe_li, h2, ids, weights, miss)
-                flops, byts = self._layer_cost(
-                    kind, x.shape, cur_len, hits=int((~miss).sum())
-                )
-                clock.compute(self.cost.compute_s(flops, byts))
-            else:
-                (block,) = self._block_fn(kind, "decode")
-                x, new_state = block(p_l, x, state if state else {}, cur)
-                self.stats.device_dispatches += 1
-                flops, byts = self._layer_cost(kind, x.shape, cur_len, hits=0)
-                clock.compute(self.cost.compute_s(flops, byts), needs_dma=False)
-            self._set_layer_state(li, new_state)
-        logits = np.asarray(self._lm_head(x[:, -1:])[:, 0])
-        self.stats.sync_pulls += 1
-        self.stats.replay_pulls += 1
-        self.stats.replayed_steps += 1
-        if tr is not None:
-            tr.complete("replay", "launch", t_trace, time.perf_counter(),
-                        args={"start_li": start_li, "step": step})
+        with maybe_span(self._tr, "replay",
+                        args={"start_li": start_li, "step": step}):
+            si0, r0 = self._moe_pos[start_moe]
+            x_anchor = aux[f"route_x/seg{si0}"]
+            if step is not None:
+                x_anchor = x_anchor[step]
+            x = x_anchor[r0].reshape(self.batch, 1, -1)
+            self.stats.device_dispatches += 1             # device-side slice
+            cur = jnp.int32(cur_len)
+            clock = self.clock
+            for li in range(start_li, len(self.layers)):
+                kind, p_l = self.layers[li]
+                state = self._layer_state(li)
+                if kind == "attn_moe":
+                    moe_li = self.moe_index[li]
+                    attn_half, moe_half = self._block_fn(kind, "decode", routed=True)
+                    x_mid, h2, ids_dev, w_dev, new_state = attn_half(p_l, x, state, cur)
+                    slots_tree = self.manager.stores[moe_li].as_pytree()
+                    lut_dev = self.manager.device_lut(moe_li)
+                    x, miss_dev = moe_half(
+                        p_l, x_mid, h2, ids_dev, w_dev, slots_tree, lut_dev
+                    )
+                    self.stats.device_dispatches += 2
+                    ids = np.asarray(ids_dev)
+                    weights = np.asarray(w_dev)
+                    miss = np.asarray(miss_dev)
+                    self.stats.sync_pulls += 1
+                    self.stats.replay_pulls += 1
+                    self.manager.record_routing(moe_li, ids, miss)
+                    if miss.any() and self.rescfg.host_compute_misses:
+                        x = self._host_correct(x, moe_li, h2, ids, weights, miss)
+                    flops, byts = self._layer_cost(
+                        kind, x.shape, cur_len, hits=int((~miss).sum())
+                    )
+                    clock.compute(self.cost.compute_s(flops, byts))
+                else:
+                    (block,) = self._block_fn(kind, "decode")
+                    x, new_state = block(p_l, x, state if state else {}, cur)
+                    self.stats.device_dispatches += 1
+                    flops, byts = self._layer_cost(kind, x.shape, cur_len, hits=0)
+                    clock.compute(self.cost.compute_s(flops, byts), needs_dma=False)
+                self._set_layer_state(li, new_state)
+            logits = np.asarray(self._lm_head(x[:, -1:])[:, 0])
+            self.stats.sync_pulls += 1
+            self.stats.replay_pulls += 1
+            self.stats.replayed_steps += 1
         return logits
 
     def _layer_cost(self, kind: str, xshape, cur_len: int, hits: int) -> Tuple[float, float]:
@@ -1732,7 +1706,8 @@ class RotaryEngine:
         if demand_dev is None:
             demand_dev = self._demand_all_jit(h_all, self._routers_next)
             self.stats.device_dispatches += 1
-        demand = np.asarray(demand_dev)
+        with maybe_span(self._tr, "demand", "pull"):
+            demand = np.asarray(demand_dev)
         self.manager.rotate_from_telemetry(
             self.predictor, ids, weights, miss, demand,
             clock=self.clock, record=False,
@@ -1787,90 +1762,87 @@ class RotaryEngine:
                 self._fused_prefill_step if last
                 else self._fused_prefill_step_nohead
             )
-            if tr is not None:
-                tr.new_unit("chunk")
-                t_trace = time.perf_counter()
-            residency = self.manager.stacked_residency()
-            logits_dev, self._dstate, aux = step_fn(
-                self._decode_params, self._routers_next,
-                jnp.asarray(tokens[:, cur : cur + c]), self._dstate,
-                jnp.int32(cur), residency,
-            )
-            self.stats.device_dispatches += 1
-            self.stats.prefill_chunks += 1
-            if tr is not None:
-                tr.complete("launch", "launch", t_trace, time.perf_counter(),
-                            args={"chunk": c, "cur_len": cur})
-            for k in self._prefill_pull_keys:
-                aux[k].copy_to_host_async()
-            self.stats.overlapped_pulls += len(self._prefill_pull_keys)
-            # dispatch the boundary demand GEMM behind the in-flight launch:
-            # its input is the step's own route_h output, so it is computed
-            # by the time the blocking telemetry pulls below drain the queue
-            # (only usable when no replay patches the hiddens — see below)
-            segs = [aux[f"route_h/seg{si}"] for si in self._moe_segs]
-            h_fast = segs[0] if len(segs) == 1 else jnp.concatenate(segs)
-            demand_dev = self._demand_all_jit(h_fast, self._routers_next)
-            self.stats.device_dispatches += 1
-            if self.prefetch:
-                # chunk launch in flight: shadow-upload the predicted next
-                # chunk-boundary transition under it
-                self.manager.begin_prefetch(self.predictor, self.clock)
-            if tr is not None:
-                t_trace = time.perf_counter()
-            if last:
-                logits = np.asarray(logits_dev)  # THE queue-draining pull
-            self.stats.sync_pulls += 1
-            # non-final chunks have no head output: the first telemetry read
-            # below is their one queue-draining pull instead
-            ids = concat_route_telemetry(aux, "ids", self._moe_segs)  # [L,T,k]
-            if tr is not None:
-                tr.complete("pull", "pull", t_trace, time.perf_counter(),
-                            args={"chunk": c})
-            weights = concat_route_telemetry(aux, "weights", self._moe_segs)
-            miss = concat_route_telemetry(aux, "miss", self._moe_segs)
-            missed = np.flatnonzero(miss.reshape(miss.shape[0], -1).any(axis=1))
-            if tr is not None and missed.size:
-                tr.instant("miss", "launch",
-                           args={"first_moe": int(missed[0]),
-                                 "layers": int(missed.size)})
-            start_moe = (
-                int(missed[0])
-                if (missed.size and self.rescfg.host_compute_misses)
-                else self.num_moe_layers
-            )
-            start_li = (
-                self._moe_layer_li[start_moe]
-                if start_moe < self.num_moe_layers
-                else len(self.layers)
-            )
-            self._account_step_prefix(ids, miss, start_li, cur, tokens=c)
-            if start_li < len(self.layers):
-                # the replay patches authoritative rows in place; telemetry
-                # views of device buffers are read-only, so copy first
-                ids, weights, miss = (
-                    np.array(a) for a in (ids, weights, miss)
+            with maybe_span(tr, "step", args={"kind": "chunk"}):
+                if tr is not None:
+                    tr.new_unit("chunk")
+                with maybe_span(tr, "launch", args={"chunk": c, "cur_len": cur}):
+                    with maybe_span(tr, "view"):
+                        residency = self.manager.stacked_residency()
+                    logits_dev, self._dstate, aux = step_fn(
+                        self._decode_params, self._routers_next,
+                        jnp.asarray(tokens[:, cur : cur + c]), self._dstate,
+                        jnp.int32(cur), residency,
+                    )
+                    self.stats.device_dispatches += 1
+                    self.stats.prefill_chunks += 1
+                for k in self._prefill_pull_keys:
+                    aux[k].copy_to_host_async()
+                self.stats.overlapped_pulls += len(self._prefill_pull_keys)
+                # dispatch the boundary demand GEMM behind the in-flight launch:
+                # its input is the step's own route_h output, so it is computed
+                # by the time the blocking telemetry pulls below drain the queue
+                # (only usable when no replay patches the hiddens — see below)
+                segs = [aux[f"route_h/seg{si}"] for si in self._moe_segs]
+                h_fast = segs[0] if len(segs) == 1 else jnp.concatenate(segs)
+                demand_dev = self._demand_all_jit(h_fast, self._routers_next)
+                self.stats.device_dispatches += 1
+                if self.prefetch:
+                    # chunk launch in flight: shadow-upload the predicted next
+                    # chunk-boundary transition under it
+                    self.manager.begin_prefetch(self.predictor, self.clock)
+                with maybe_span(tr, "pull", "pull", args={"chunk": c}):
+                    if last:
+                        logits = np.asarray(logits_dev)  # THE queue-draining pull
+                    self.stats.sync_pulls += 1
+                    # non-final chunks have no head output: the first telemetry
+                    # read is their one queue-draining pull instead
+                    ids = concat_route_telemetry(aux, "ids", self._moe_segs)
+                with maybe_span(tr, "telemetry", "pull"):
+                    weights = concat_route_telemetry(aux, "weights", self._moe_segs)
+                    miss = concat_route_telemetry(aux, "miss", self._moe_segs)
+                missed = np.flatnonzero(miss.reshape(miss.shape[0], -1).any(axis=1))
+                if tr is not None and missed.size:
+                    tr.instant("miss", "launch",
+                               args={"first_moe": int(missed[0]),
+                                     "layers": int(missed.size)})
+                start_moe = (
+                    int(missed[0])
+                    if (missed.size and self.rescfg.host_compute_misses)
+                    else self.num_moe_layers
                 )
-                h_rows = [
-                    aux[f"route_h/seg{si}"][r]
-                    for si, r in self._moe_pos
-                ]                               # per MoE layer: [T, D] device
-                replay_logits = self._replay_prefill_chunk(
-                    aux, start_moe, start_li, cur, c,
-                    ids, weights, miss, h_rows, with_head=last,
+                start_li = (
+                    self._moe_layer_li[start_moe]
+                    if start_moe < self.num_moe_layers
+                    else len(self.layers)
                 )
-                if last:
-                    logits = replay_logits
-                # the replay patched the hiddens — the optimistic GEMM read
-                # stale rows; re-run it over the authoritative stack
-                self._rotate_chunk_boundary(
-                    ids, weights, miss, h_all=jnp.stack(h_rows)
-                )
-            else:
-                self._rotate_chunk_boundary(
-                    ids, weights, miss, demand_dev=demand_dev
-                )
-            cur += c
+                with maybe_span(tr, "account"):
+                    self._account_step_prefix(ids, miss, start_li, cur, tokens=c)
+                if start_li < len(self.layers):
+                    # the replay patches authoritative rows in place; telemetry
+                    # views of device buffers are read-only, so copy first
+                    ids, weights, miss = (
+                        np.array(a) for a in (ids, weights, miss)
+                    )
+                    h_rows = [
+                        aux[f"route_h/seg{si}"][r]
+                        for si, r in self._moe_pos
+                    ]                               # per MoE layer: [T, D] device
+                    replay_logits = self._replay_prefill_chunk(
+                        aux, start_moe, start_li, cur, c,
+                        ids, weights, miss, h_rows, with_head=last,
+                    )
+                    if last:
+                        logits = replay_logits
+                    # the replay patched the hiddens — the optimistic GEMM read
+                    # stale rows; re-run it over the authoritative stack
+                    self._rotate_chunk_boundary(
+                        ids, weights, miss, h_all=jnp.stack(h_rows)
+                    )
+                else:
+                    self._rotate_chunk_boundary(
+                        ids, weights, miss, demand_dev=demand_dev
+                    )
+                cur += c
         return logits
 
     def _replay_prefill_chunk(
@@ -1903,53 +1875,50 @@ class RotaryEngine:
         chunk but the prompt's last) skips the lm-head GEMM and its logits
         pull — only the final chunk's logits are consumed.
         """
-        tr = self._tr
-        t_replay = time.perf_counter() if tr is not None else 0.0
-        si0, r0 = self._moe_pos[start_moe]
-        x = aux[f"route_x/seg{si0}"][r0].reshape(self.batch, chunk, -1)
-        self.stats.device_dispatches += 1             # device-side slice
-        cur = jnp.int32(cur_len)
-        clock = self.clock
-        for li in range(start_li, len(self.layers)):
-            kind, p_l = self.layers[li]
-            state = self._layer_state(li)
-            if kind == "attn_moe":
-                moe_li = self.moe_index[li]
-                attn_half, moe_half = self._block_fn(kind, "chunk", routed=True)
-                x_mid, h2, ids_dev, w_dev, new_state = attn_half(p_l, x, state, cur)
-                slots_tree = self.manager.stores[moe_li].as_pytree()
-                lut_dev = self.manager.device_lut(moe_li)
-                x, miss_dev = moe_half(
-                    p_l, x_mid, h2, ids_dev, w_dev, slots_tree, lut_dev
-                )
-                self.stats.device_dispatches += 2
-                ids = np.asarray(ids_dev)
-                weights = np.asarray(w_dev)
-                miss = np.asarray(miss_dev)
-                self.stats.sync_pulls += 1
-                self.stats.replay_pulls += 1
-                self.manager.record_routing(moe_li, ids, miss)
-                if miss.any() and self.rescfg.host_compute_misses:
-                    x = self._host_correct(x, moe_li, h2, ids, weights, miss)
-                ids_all[moe_li] = ids
-                weights_all[moe_li] = weights
-                miss_all[moe_li] = miss
-                h_rows[moe_li] = h2.reshape(-1, x.shape[-1])
-                flops, byts = self._layer_cost(
-                    kind, x.shape, cur_len, hits=int((~miss).sum())
-                )
-                clock.compute(self.cost.compute_s(flops, byts))
-            else:
-                (block,) = self._block_fn(kind, "chunk")
-                x, new_state = block(p_l, x, state if state else {}, cur)
-                self.stats.device_dispatches += 1
-                flops, byts = self._layer_cost(kind, x.shape, cur_len, hits=0)
-                clock.compute(self.cost.compute_s(flops, byts), needs_dma=False)
-            self._set_layer_state(li, new_state)
-        self.stats.prefill_replays += 1
-        if tr is not None:
-            tr.complete("replay", "launch", t_replay, time.perf_counter(),
-                        args={"start_li": start_li, "chunk": chunk})
+        with maybe_span(self._tr, "replay",
+                        args={"start_li": start_li, "chunk": chunk}):
+            si0, r0 = self._moe_pos[start_moe]
+            x = aux[f"route_x/seg{si0}"][r0].reshape(self.batch, chunk, -1)
+            self.stats.device_dispatches += 1             # device-side slice
+            cur = jnp.int32(cur_len)
+            clock = self.clock
+            for li in range(start_li, len(self.layers)):
+                kind, p_l = self.layers[li]
+                state = self._layer_state(li)
+                if kind == "attn_moe":
+                    moe_li = self.moe_index[li]
+                    attn_half, moe_half = self._block_fn(kind, "chunk", routed=True)
+                    x_mid, h2, ids_dev, w_dev, new_state = attn_half(p_l, x, state, cur)
+                    slots_tree = self.manager.stores[moe_li].as_pytree()
+                    lut_dev = self.manager.device_lut(moe_li)
+                    x, miss_dev = moe_half(
+                        p_l, x_mid, h2, ids_dev, w_dev, slots_tree, lut_dev
+                    )
+                    self.stats.device_dispatches += 2
+                    ids = np.asarray(ids_dev)
+                    weights = np.asarray(w_dev)
+                    miss = np.asarray(miss_dev)
+                    self.stats.sync_pulls += 1
+                    self.stats.replay_pulls += 1
+                    self.manager.record_routing(moe_li, ids, miss)
+                    if miss.any() and self.rescfg.host_compute_misses:
+                        x = self._host_correct(x, moe_li, h2, ids, weights, miss)
+                    ids_all[moe_li] = ids
+                    weights_all[moe_li] = weights
+                    miss_all[moe_li] = miss
+                    h_rows[moe_li] = h2.reshape(-1, x.shape[-1])
+                    flops, byts = self._layer_cost(
+                        kind, x.shape, cur_len, hits=int((~miss).sum())
+                    )
+                    clock.compute(self.cost.compute_s(flops, byts))
+                else:
+                    (block,) = self._block_fn(kind, "chunk")
+                    x, new_state = block(p_l, x, state if state else {}, cur)
+                    self.stats.device_dispatches += 1
+                    flops, byts = self._layer_cost(kind, x.shape, cur_len, hits=0)
+                    clock.compute(self.cost.compute_s(flops, byts), needs_dma=False)
+                self._set_layer_state(li, new_state)
+            self.stats.prefill_replays += 1
         if not with_head:
             return None
         logits = np.asarray(self._lm_head(x[:, -1:])[:, 0])
@@ -2001,46 +1970,51 @@ class RotaryEngine:
                 self._sample_fns[sp] = sample_fn
             sample_rng = np.random.default_rng(sampler.seed)
         spec = self._fused_decode and self.spec_k > 1
+        # units and ``step`` spans belong to the fused paths, whose launches
+        # and pulls they group
+        tr = self._tr if self._fused_decode else None
         t0 = time.perf_counter()
         i = 0
         while i < steps:
-            if sampled:
-                tok = np.asarray(sample_fn(
-                    jnp.asarray(logits), base_keys,
-                    jnp.int32(self.cur_len - 1),
-                ))
-                self.stats.sync_pulls += 1
-            else:
-                tok = np.argmax(logits, axis=-1).astype(np.int32)
-            out[:, i] = tok
-            t_win = time.perf_counter()
             k = min(self.spec_k, steps - i) if spec else 1
-            if k > 1 or (sampled and self._fused_decode):
-                extra, logits, committed = self._decode_window_fused(
-                    tok, k, sample=sp, rng_keys=base_keys,
-                    sample_rng=sample_rng,
-                )
-                if committed > 1:
-                    out[:, i + 1 : i + committed] = extra.T
-                advanced = committed
-            else:
-                if self._fused_decode:
-                    logits = self._decode_step_fused(tok)
-                elif self._hot_decode:
-                    logits = self._decode_step_hot(tok)
+            window = k > 1 or (sampled and self._fused_decode)
+            kind = "window" if window else "decode"
+            with maybe_span(tr, "step", args={"kind": kind}):
+                if tr is not None:
+                    tr.new_unit(kind)
+                with maybe_span(tr, "sample"):
+                    if sampled:
+                        tok = np.asarray(sample_fn(
+                            jnp.asarray(logits), base_keys,
+                            jnp.int32(self.cur_len - 1),
+                        ))
+                        self.stats.sync_pulls += 1
+                    else:
+                        tok = np.argmax(logits, axis=-1).astype(np.int32)
+                out[:, i] = tok
+                if window:
+                    extra, logits, committed = self._decode_window_fused(
+                        tok, k, sample=sp, rng_keys=base_keys,
+                        sample_rng=sample_rng,
+                    )
+                    if committed > 1:
+                        out[:, i + 1 : i + committed] = extra.T
+                    advanced = committed
                 else:
-                    x = self._embed(jnp.asarray(tok)[:, None])
-                    x = self._run_layers(x, "decode", cur_len=self.cur_len)
-                    logits = np.asarray(self._lm_head(x[:, -1:])[:, 0])
-                    self.stats.sync_pulls += 1
-                advanced = 1
-            i += advanced
-            self.cur_len += advanced
-            self.stats.steps += advanced
-            self.stats.tokens += self.batch * advanced
-            self.metrics.histogram(
-                "window_ms", "wall ms per decode step/window"
-            ).observe((time.perf_counter() - t_win) * 1e3)
+                    if self._fused_decode:
+                        logits = self._decode_step_fused(tok)
+                    elif self._hot_decode:
+                        logits = self._decode_step_hot(tok)
+                    else:
+                        x = self._embed(jnp.asarray(tok)[:, None])
+                        x = self._run_layers(x, "decode", cur_len=self.cur_len)
+                        logits = np.asarray(self._lm_head(x[:, -1:])[:, 0])
+                        self.stats.sync_pulls += 1
+                    advanced = 1
+                i += advanced
+                self.cur_len += advanced
+                self.stats.steps += advanced
+                self.stats.tokens += self.batch * advanced
         self.stats.wall_s += time.perf_counter() - t0
         self.stats.compute_s = self.clock.compute_s
         self.stats.transfer_s = self.clock.transfer_s

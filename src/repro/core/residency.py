@@ -49,7 +49,7 @@ from repro.core.slots import (
 from repro.core.stats import EngineStats
 from repro.core.transfer import CostModel, TransferClock
 from repro.obs.metrics import BYTES_BUCKETS
-from repro.obs.tracer import resolve_tracer
+from repro.obs.tracer import maybe_span, resolve_tracer
 
 
 # Dirty-slot patches into the persistent stacked planes: one dispatch per
@@ -370,41 +370,46 @@ class RotaryResidencyManager:
         dispatch per expert); old buffers are donated when the owning engine
         marked it safe. ``shadow`` lands the bytes in the store's shadow
         generation (speculative prefetch: the in-flight launch keeps reading
-        untouched live buffers) instead of the live one."""
+        untouched live buffers) instead of the live one.
+
+        Traced as one ``upload`` span (``layer``, ``bytes``, ``n``,
+        ``shadow``): the host's gather of the expert rows and its dispatch
+        of the asynchronous copy and scatter, not the transfer itself, which
+        runs on after the span closes."""
         if not loads:
             return 0
         hw = self.host_experts[layer]
         store = self.stores[layer]
         experts = np.asarray([e for e, _ in loads], np.int64)
         slots = [s for _, s in loads]
-        if (
-            not shadow
-            and self._planes is not None
-            and store.quantization is None
-            and layer in self._seg_of_layer
-        ):
-            moved = self._write_through_loads(layer, slots, experts)
-        else:
-            before = store.dispatches
-            moved = store.write_batch(
-                slots, {n: hw[n][experts] for n in hw},
-                donate=self.donate_buffers, shadow=shadow,
-            )
-            self.stats.upload_dispatches += store.dispatches - before
-            self.stats.device_dispatches += store.dispatches - before
-            self.stats.bytes_uploaded += moved
-            if not shadow:
-                self._stacked_dirty[layer].update(int(s) for _, s in loads)
-                self.generation += 1
+        track = "prefetch" if shadow else "rotation"
+        with maybe_span(self.tracer, "upload", track) as span:
+            if (
+                not shadow
+                and self._planes is not None
+                and store.quantization is None
+                and layer in self._seg_of_layer
+            ):
+                moved = self._write_through_loads(layer, slots, experts)
+            else:
+                before = store.dispatches
+                moved = store.write_batch(
+                    slots, {n: hw[n][experts] for n in hw},
+                    donate=self.donate_buffers, shadow=shadow,
+                )
+                self.stats.upload_dispatches += store.dispatches - before
+                self.stats.device_dispatches += store.dispatches - before
+                self.stats.bytes_uploaded += moved
+                if not shadow:
+                    self._stacked_dirty[layer].update(int(s) for _, s in loads)
+                    self.generation += 1
+            if span is not None:
+                span.args = {"layer": layer, "bytes": moved, "n": len(loads),
+                             "shadow": shadow}
         if self._live_contents is not None:
             tracked = self._shadow_contents if shadow else self._live_contents
             for e, s in loads:
                 tracked[layer][int(s)] = int(e)
-        tr = self.tracer
-        if tr is not None:
-            tr.instant("upload", "prefetch" if shadow else "rotation",
-                       args={"layer": layer, "bytes": moved,
-                             "n": len(loads), "shadow": shadow})
         if self.metrics is not None:
             self.metrics.histogram(
                 "upload_bytes", "bytes per slot-upload dispatch",
@@ -748,11 +753,8 @@ class RotaryResidencyManager:
         clock: Optional[TransferClock] = None,
         record: bool = True,
     ) -> None:
-        tr = self.tracer
-        if tr is None:
-            return self._rotate_from_telemetry(
-                predictor, ids, weights, miss, demand_next, clock, record)
-        with tr.span("rotation", "rotation", args={"kind": "step"}):
+        with maybe_span(self.tracer, "rotation", "rotation",
+                        args={"kind": "step"}):
             return self._rotate_from_telemetry(
                 predictor, ids, weights, miss, demand_next, clock, record)
 
@@ -818,12 +820,8 @@ class RotaryResidencyManager:
         record: bool = True,
         accepted: Optional[np.ndarray] = None,
     ) -> None:
-        tr = self.tracer
-        if tr is None:
-            return self._rotate_window_from_telemetry(
-                predictor, ids, weights, miss, demand_next, clock, record,
-                accepted)
-        with tr.span("rotation", "rotation", args={"kind": "window"}):
+        with maybe_span(self.tracer, "rotation", "rotation",
+                        args={"kind": "window"}):
             return self._rotate_window_from_telemetry(
                 predictor, ids, weights, miss, demand_next, clock, record,
                 accepted)

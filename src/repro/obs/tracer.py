@@ -6,16 +6,26 @@ machine in the engine gets its own *track* (launch / pull / rotation /
 prefetch / kv_pool / request) and, in serving, every request gets its own
 *lane* so the Perfetto timeline shows one row per in-flight request.
 
+While a :meth:`Tracer.span` is open it also holds a profiler TraceMe
+(:class:`jax.profiler.TraceAnnotation`) named ``engine/<name>``, so a trace
+captured with :func:`jax.profiler.start_trace` shows the engine's phases on
+the host plane, on the same clock as the runtime's own launch and completion
+events.  Records stamped after the fact (:meth:`Tracer.complete`) and
+instants are not forwarded.
+
 Tracing is opt-in.  The engines normalise ``trace=None`` (and any tracer with
-``enabled=False``) to *no tracer at all* — every emission site is guarded by
-a plain ``if tr is not None`` so the tracing-off hot path executes exactly
-the same instructions as before this subsystem existed.  That is the
-"disabled overhead is unmeasurable" contract the decode benchmark asserts
-structurally (see ``benchmarks/decode_hot_path.py``).
+``enabled=False``) to *no tracer at all*.  Instants and after-the-fact spans
+are guarded by a plain ``if tr is not None``; a span site enters
+``maybe_span(tr, ...)``, which with no tracer is the shared do-nothing
+context :data:`NO_SPAN`: no clock is read and nothing is recorded.  The
+decode benchmark asserts the "no tracer reference" half structurally (see
+``benchmarks/decode_hot_path.py``).
 
 Span records carry the tracer's *current unit* — a monotonically increasing
 id the engine bumps once per decode step / spec window / prefill chunk /
-serving tick via :meth:`Tracer.new_unit`.  The contract auditor
+serving tick via :meth:`Tracer.new_unit`.  A span takes the unit current
+when it closes, so the ``step`` span that encloses a decode step or prefill
+chunk carries the unit opened inside it.  The contract auditor
 (``repro.obs.audit``) groups events by unit to check the standing dispatch
 invariants (one launch + one queue-draining pull per miss-free unit,
 rotation strictly after the pull, prefetch ship strictly between launch and
@@ -26,7 +36,10 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
+from contextlib import nullcontext
 from typing import Any, Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # Track names in display order.  Chrome trace tids are assigned from this
 # list first so the Perfetto timeline always shows the machines in pipeline
@@ -36,11 +49,19 @@ MACHINE_TRACKS = ("launch", "pull", "rotation", "prefetch", "kv_pool")
 _PID_MACHINES = 1
 _PID_REQUESTS = 2
 
+# Profiler names are ``engine/<span name>``: the prefix keeps them apart from
+# the annotations a caller opens itself (``window``, ``prefill``, ``decode``).
+PROFILER_PREFIX = "engine/"
+
+# What a span site enters when tracing is off.
+NO_SPAN = nullcontext()
+
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, and
+    holding a profiler TraceMe named ``engine/<name>`` while open."""
 
-    __slots__ = ("_tr", "name", "track", "args", "t0", "duration_s")
+    __slots__ = ("_tr", "name", "track", "args", "t0", "duration_s", "_tm")
 
     def __init__(self, tr: "Tracer", name: str, track: str, args):
         self._tr = tr
@@ -51,11 +72,14 @@ class _Span:
         self.duration_s = 0.0
 
     def __enter__(self) -> "_Span":
+        self._tm = TraceAnnotation(PROFILER_PREFIX + self.name)
+        self._tm.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        self._tm.__exit__(exc_type, exc, tb)
         self.duration_s = t1 - self.t0
         tr = self._tr
         tr._buf.append(
@@ -128,16 +152,6 @@ class Tracer:
     def records(self) -> List[tuple]:
         return list(self._buf)
 
-    def overlap_ms(self) -> float:
-        """Span-derived prefetch overlap: total prefetch-ship duration.
-
-        This is the trace-native replacement for the wall-clock side
-        channel the residency manager keeps in ``EngineStats.overlap_ms``;
-        a regression test checks the two agree on a miss-starved run.
-        """
-        return sum(r[5] for r in self._buf
-                   if r[0] == "X" and r[1] == "prefetch_ship") * 1e3
-
     # -------------------------------------------------------------- export
     def chrome_trace(self) -> Dict[str, Any]:
         """Render the buffer as a Chrome trace-event JSON object.
@@ -202,6 +216,13 @@ def resolve_tracer(trace: Optional[Tracer]) -> Optional[Tracer]:
     if trace is None or not trace.enabled:
         return None
     return trace
+
+
+def maybe_span(tr: Optional[Tracer], name: str, track: str = "launch",
+               args: Optional[Dict[str, Any]] = None):
+    """``tr.span(...)``, or :data:`NO_SPAN` when ``tr`` is None (tracing
+    off): a span site reads ``with maybe_span(tr, "pull", "pull"): ...``."""
+    return NO_SPAN if tr is None else tr.span(name, track, args)
 
 
 def span_overlap_ms(events: Iterable[Dict[str, Any]]) -> float:

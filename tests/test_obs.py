@@ -297,10 +297,100 @@ def test_overlap_ms_spans_agree_with_legacy_stats():
     cfg, params = params_for("qwen2-moe-a2.7b")
     eng, tr = _trace_rotary(cfg, params, steps=6, prefetch=True)
     stats_ms = eng.stats.overlap_ms
-    span_ms = tr.overlap_ms()
+    span_ms = audit(tr).overlap_ms
     assert stats_ms > 0
     assert span_ms == pytest.approx(stats_ms, rel=0.01, abs=1.0)
-    assert audit(tr).overlap_ms == pytest.approx(span_ms, abs=0.01)
+    # the export's rounded microseconds lose nothing the check could see
+    recorded_ms = 1e3 * sum(r[5] for r in tr.records()
+                            if r[1] == "prefetch_ship")
+    assert span_ms == pytest.approx(recorded_ms, abs=0.01)
+
+
+# ===========================================================================
+# step spans: every fused decode step and prefill chunk is one named span
+# ===========================================================================
+# The phases inside a unit, in the order they start.
+PHASES = ("launch", "pull", "telemetry", "account", "rotation")
+FORWARDED_PREFIX = "engine/"
+
+
+def _units(tr):
+    """{unit: (kind, [records])} of every unit the engine opened."""
+    kinds = {r[6]: r[7]["kind"] for r in tr.records() if r[1] == "unit"}
+    out = {u: (k, []) for u, k in kinds.items()}
+    for r in tr.records():
+        if r[6] in out and r[1] != "unit":
+            out[r[6]][1].append(r)
+    return out
+
+
+def _chunked():
+    cfg, params = params_for("qwen2-moe-a2.7b")
+    return _trace_rotary(cfg, params, prefill_chunk=8)
+
+
+def test_every_decode_and_chunk_unit_has_one_step_span():
+    _, tr = _chunked()
+    units = _units(tr)
+    assert {k for k, _ in units.values()} == {"chunk", "decode"}
+    for u, (kind, recs) in units.items():
+        steps = [r for r in recs if r[1] == "step"]
+        assert len(steps) == 1, (u, kind, len(steps))
+        assert steps[0][0] == "X" and steps[0][7] == {"kind": kind}
+
+
+def test_unit_spans_lie_inside_their_step_in_phase_order():
+    _, tr = _chunked()
+    for u, (kind, recs) in _units(tr).items():
+        (step,) = [r for r in recs if r[1] == "step"]
+        s0, s1 = step[4], step[4] + step[5]
+        for r in recs:
+            assert s0 <= r[4] and r[4] + r[5] <= s1, (u, r[1])
+        first = {}
+        for r in recs:
+            first.setdefault(r[1], r[4])
+        names = {r[1] for r in recs}
+        assert set(PHASES) <= names, (u, kind, names)
+        assert [first[n] for n in PHASES] == sorted(first[n] for n in PHASES)
+        assert "view" in names and ("demand" in names) == (kind == "chunk")
+        # view nests inside the launch, and (decode) sample precedes it
+        (launch,) = [r for r in recs if r[1] == "launch"]
+        for v in (r for r in recs if r[1] == "view"):
+            assert launch[4] <= v[4] and v[4] + v[5] <= launch[4] + launch[5]
+        if kind == "decode":
+            assert first["sample"] <= first["launch"]
+        if kind == "chunk":
+            assert first["account"] <= first["demand"] <= first["rotation"]
+
+
+def test_spans_are_forwarded_to_the_profiler_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg, params = params_for("qwen2-moe-a2.7b")
+    eng, tr = _trace_rotary(cfg, params, prefill_chunk=8)   # compiled here
+    n0 = len(tr)
+    prompt = (np.random.default_rng(1)
+              .integers(0, cfg.vocab_size, (1, 8)).astype(np.int32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.generate(prompt, 3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for ev in line.events]
+    forwarded = sorted(n[len(FORWARDED_PREFIX):] for n in names
+                       if n.startswith(FORWARDED_PREFIX))
+    spans = sorted(r[1] for r in tr.records()[n0:] if r[0] == "X")
+    assert spans and forwarded == spans
+    assert {"step", "view", "launch", "pull", "telemetry", "account",
+            "demand", "sample", "rotation", "upload"} <= set(spans)
+    # the benchmark selects its own annotations by these exact names
+    assert not {"window", "prefill", "decode"} & set(names)
 
 
 def test_tracing_off_is_structurally_free():
