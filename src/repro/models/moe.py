@@ -265,6 +265,27 @@ def moe_epsum_local(
 # ---------------------------------------------------------------------------
 # gathered decode: per-token expert weights, optionally through the slot LUT
 # ---------------------------------------------------------------------------
+def _slot_rows(
+    plane: jax.Array, gidx: jax.Array, layer: Optional[jax.Array]
+) -> jax.Array:
+    """The rows ``plane[layer, gidx]`` ([L, S+1, ...] stacked plane) or
+    ``plane[gidx]`` ([S+1, ...] one layer's plane), as [*gidx.shape, ...].
+
+    Each routed pick is one ``lax.dynamic_slice`` of its row, so the plane is
+    read where it lies: a stacked plane closed over by the layer scan is never
+    sliced or copied per layer, only the picked rows move.
+    """
+    row = plane.shape[-2:]
+    lead = (layer,) if plane.ndim == 4 else ()
+    sizes = (1,) * (len(lead) + 1) + row
+
+    def one(g):
+        start = lead + (g,) + (0,) * len(row)
+        return jax.lax.dynamic_slice(plane, start, sizes).reshape(row)
+
+    return jax.vmap(one)(gidx.reshape(-1)).reshape(gidx.shape + row)
+
+
 def moe_apply_routed(
     p: Params,
     x2d: jax.Array,
@@ -273,29 +294,30 @@ def moe_apply_routed(
     *,
     slot_buffer: Optional[Params] = None,
     lut: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
     include_shared: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply already-routed experts via gathered weights (engine path).
 
     Same compute as ``moe_gathered`` but routing is supplied by the caller so the
     rotary engine can resolve the LUT / issue blocking loads BEFORE compute.
-    Returns (y [T,D], miss [T,k]).
+    ``slot_buffer`` planes are one layer's ([S+1, ...]) or the whole stack's
+    ([L, S+1, ...], read at ``layer``); either way only the routed rows are
+    read (:func:`_slot_rows`). Returns (y [T,D], miss [T,k]).
     """
     if slot_buffer is not None:
         assert lut is not None
-        num_slots = slot_buffer["w_up"].shape[0] - 1
+        num_slots = slot_buffer["w_up"].shape[-3] - 1
         slots = lut[ids]
         miss = slots >= num_slots
-        src = slot_buffer
         gidx = jnp.where(miss, num_slots, slots)
+        w = {n: _slot_rows(v, gidx, layer) for n, v in slot_buffer.items()}
     else:
         miss = jnp.zeros(ids.shape, bool)
-        src = p["experts"]
-        gidx = ids
-    wq = jnp.take(src["w_up"], gidx, axis=0)
-    wd = jnp.take(src["w_down"], gidx, axis=0)
-    if "w_gate" in src:
-        wg = jnp.take(src["w_gate"], gidx, axis=0)
+        w = {n: jnp.take(v, ids, axis=0) for n, v in p["experts"].items()}
+    wq, wd = w["w_up"], w["w_down"]
+    if "w_gate" in w:
+        wg = w["w_gate"]
         h = jax.nn.silu(jnp.einsum("td,tkdf->tkf", x2d, wg)) * jnp.einsum(
             "td,tkdf->tkf", x2d, wq
         )

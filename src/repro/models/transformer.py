@@ -314,9 +314,10 @@ def _apply_block(
         h = apply_norm(cfg.norm, p["ln2"], x)
         if kind == "attn_moe":
             if mode in ("decode", "chunk"):
-                slot_buffer = lut = None
+                slot_buffer = lut = layer = None
                 if residency is not None:
                     slot_buffer, lut = residency["slots"], residency["lut"]
+                    layer = residency.get("layer")
                 h2d = h.reshape(-1, d)
                 logits = moe_mod.router_logits(p["moe"], h2d)
                 # the fused Pallas gate on TPU (lax.top_k elsewhere)
@@ -353,7 +354,7 @@ def _apply_block(
                 else:
                     y2, miss = moe_mod.moe_apply_routed(
                         p["moe"], h2d, ids, weights,
-                        slot_buffer=slot_buffer, lut=lut,
+                        slot_buffer=slot_buffer, lut=lut, layer=layer,
                     )
                 aux["moe_miss"] = miss.sum()
                 # routing telemetry for the rotary engine/predictor ("route_*"
@@ -529,20 +530,27 @@ def _run_stack(
     """Scan the segment stack. residency: per-MoE-layer {slots, lut} stacked
     over reps; ``page_table`` [B, pages] switches decode-mode KV blocks to the
     paged pool layout (shared across layers — every layer's plane is carved
-    identically, so one table addresses them all)."""
+    identically, so one table addresses them all).
+
+    The stacked slot planes ([reps, S+1, ...]) are closed over, not scanned:
+    the scan carries each rep's layer index beside its LUT row, and the MoE
+    block reads only its routed rows at (layer, slot). Scanning the planes as
+    xs would copy every layer's whole plane each step."""
     aux_tot: Dict[str, jax.Array] = {}
     new_states: List[Any] = []
     for si, (unit, reps) in enumerate(cfg.segments):
         unit_params = params["segments"][si]
         # scan xs must be uniform pytrees: {} stands in for "no state"/"no residency"
         unit_state = state[si] if state is not None else tuple({} for _ in unit)
-        unit_res = {}
+        unit_res, planes = {}, None
         if residency is not None and any(k == "attn_moe" for k in unit):
-            unit_res = residency[si]
+            planes = residency[si]["slots"]
+            unit_res = {"lut": residency[si]["lut"],
+                        "layer": jnp.arange(reps, dtype=jnp.int32)}
 
-        def unit_fn(x, per_rep, unit=unit):
+        def unit_fn(x, per_rep, unit=unit, planes=planes):
             p_list, s_list, r = per_rep
-            r = r if r else None
+            r = {"slots": planes, **r} if r else None
             new_s = []
             aux_u: Dict[str, jax.Array] = {}
             for pi, kind in enumerate(unit):

@@ -17,8 +17,14 @@ import pytest
 from conftest import params_for
 from repro.config import ResidencyConfig
 from repro.core import RotaryEngine, SlotStore
+from repro.core.engine import (
+    build_fused_decode_step,
+    build_fused_prefill_step,
+    build_fused_window_step,
+)
 from repro.core.rotation import RotaryRing
 from repro.models import init_params
+from repro.models import transformer as tfm
 from repro.models.transformer import Runtime
 from repro.serving.scheduler import Scheduler
 
@@ -410,3 +416,104 @@ def test_prefetch_tokens_identical_to_sync(rng, mode, slots, quant, spec_k):
         # every miss was resolved by the compiled-step relaunch or, past the
         # iteration cap, the replay fallback — never silently dropped
         assert s.relaunched_steps + s.replayed_steps > 0
+
+
+# ===========================================================================
+# stacked slot planes read at (layer, slot)
+# ===========================================================================
+def _walk_step(cfg, params, rt, tokens, state, cur_len, mode, planes, lut):
+    """One step as a per-layer walk: every MoE layer reads its own
+    [S+1, ...] plane and LUT row, the unfused path's plane rank."""
+    block = jax.jit(
+        lambda p, x, st, cl, res: tfm._apply_block(
+            "attn_moe", p, cfg, rt, x, mode, st, cl, res
+        )
+    )
+    x = jax.jit(lambda p, t: tfm.embed_tokens(cfg, p, t))(params, tokens)
+    unit_p, unit_s = params["segments"][0][0], state[0][0]
+    new_s, miss = [], []
+    for layer in range(lut.shape[0]):
+        at = lambda a: a[layer]
+        res = {"slots": jax.tree.map(at, planes), "lut": lut[layer]}
+        x, ns, aux = block(
+            jax.tree.map(at, unit_p), x, jax.tree.map(at, unit_s), cur_len, res
+        )
+        new_s.append(ns)
+        miss.append(aux["route_miss"])
+    logits = jax.jit(
+        lambda p, h: tfm.lm_logits(cfg, p, h[:, -1:])[:, 0]
+    )(params, x)
+    state = ((jax.tree.map(lambda *a: jnp.stack(a), *new_s),),)
+    return logits, state, jnp.stack(miss)
+
+
+def test_fused_steps_read_each_layers_slot_rows(rng):
+    """The fused steps close over the stacked slot planes and read each
+    routed row at (layer, slot). Three MoE layers hold different resident
+    experts in planes that differ per layer, and most picks fall on the miss
+    slot: fused chunk, decode and window logits and ``route_miss`` equal the
+    per-layer walk bitwise, so a wrong layer index or miss row shows."""
+    cfg, _ = params_for("qwen2-moe-a2.7b")
+    cfg = dataclasses.replace(
+        cfg, dtype="float32", segments=((("attn_moe",), 3),)
+    )
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rt = Runtime(cache_len=32)
+    n_layers, n_exp, n_slots = 3, cfg.moe.storage_experts, 3
+    experts = params["segments"][0][0]["moe"]["experts"]
+    lut = np.full((n_layers, n_exp), n_slots, np.int32)
+    rows = np.zeros((n_layers, n_slots + 1), np.int32)
+    for layer in range(n_layers):
+        resident = rng.permutation(n_exp)[:n_slots]
+        lut[layer, resident] = np.arange(n_slots)
+        rows[layer, :n_slots] = resident
+    zero_row = np.arange(n_slots + 1) == n_slots
+    planes = {
+        n: jnp.where(
+            zero_row[None, :, None, None], 0.0,
+            jnp.take_along_axis(w, rows[:, :, None, None], axis=1),
+        )
+        for n, w in experts.items()
+    }
+    lut = jnp.asarray(lut)
+    residency = ({"slots": planes, "lut": lut},)
+    state = tfm.zero_state(cfg, 2, rt.cache_len)
+    prompt = jnp.asarray(rng.integers(0, 200, (2, 4)), jnp.int32)
+
+    chunk = build_fused_prefill_step(
+        cfg, rt, with_demand=False, donate_state=False
+    )
+    lg_f, st_f, aux = chunk(params, None, prompt, state, jnp.int32(0), residency)
+    lg_w, st_w, miss_w = _walk_step(
+        cfg, params, rt, prompt, state, jnp.int32(0), "chunk", planes, lut
+    )
+    np.testing.assert_array_equal(lg_f, lg_w)
+    np.testing.assert_array_equal(aux["route_miss/seg0"], miss_w)
+    for a, b in zip(jax.tree.leaves(st_f), jax.tree.leaves(st_w)):
+        np.testing.assert_array_equal(a, b)
+    assert 0 < int(miss_w.sum()) < miss_w.size      # hits and misses both
+
+    tok = jnp.argmax(lg_w, axis=-1).astype(jnp.int32)
+    decode = build_fused_decode_step(cfg, rt, with_demand=False, donate_state=False)
+    lg_f, _, aux = decode(params, None, tok, st_w, jnp.int32(4), residency)
+    lg_w, _, miss_w = _walk_step(
+        cfg, params, rt, tok[:, None], st_w, jnp.int32(4), "decode", planes, lut
+    )
+    np.testing.assert_array_equal(lg_f, lg_w)
+    np.testing.assert_array_equal(aux["route_miss/seg0"], miss_w)
+
+    k_steps = 3
+    window = build_fused_window_step(
+        cfg, rt, k_steps, with_demand=False, donate_state=False
+    )
+    draft, last, _, aux = window(params, None, tok, st_w, jnp.int32(4), residency)
+    st, cur = st_w, tok
+    for j in range(k_steps):
+        lg_w, st, miss_w = _walk_step(
+            cfg, params, rt, cur[:, None], st, jnp.int32(4 + j), "decode",
+            planes, lut,
+        )
+        cur = jnp.argmax(lg_w, axis=-1).astype(jnp.int32)
+        np.testing.assert_array_equal(draft[j], cur)
+        np.testing.assert_array_equal(aux["route_miss/seg0"][j], miss_w)
+    np.testing.assert_array_equal(last, lg_w)
