@@ -32,17 +32,19 @@ def run() -> List[Dict]:
     rng = np.random.default_rng(0)
     rows = []
 
-    # slot-LUT grouped matmul
+    # slot grouped matmul: e groups of c rows, each on its slot plane
     e, c, d, f, s = 8, 64, 256, 512, 6
-    x = jnp.asarray(rng.standard_normal((e, c, d)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((s + 1, d, f)), jnp.float32)
-    lut = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
-    jit_ref = jax.jit(lambda x, w, l: ref.slot_gmm_ref(x, w, l))
-    t_ref = _time(jit_ref, x, w, lut)
-    out_k = ops.slot_gmm(x, w, lut, block_c=64, block_f=128, block_d=128)
-    err = float(jnp.abs(out_k - jit_ref(x, w, lut)).max())
+    sizes = jnp.full((e,), c, jnp.int32)
+    planes = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
+    jit_ref = jax.jit(lambda x, w, n, p: ref.slot_gmm_ref(x, w, n, p))
+    t_ref = _time(jit_ref, x, w, sizes, planes)
+    out_k = ops.slot_gmm(x, w, sizes, planes, block_m=64, block_k=128,
+                         block_n=128)
+    err = float(jnp.abs(out_k - jit_ref(x, w, sizes, planes)).max())
     flops = 2 * e * c * d * f
-    bytes_ = (e * c * d + e * c * f) * 4 + (s + 1) * d * f * 4
+    bytes_ = (e * c * d + e * c * f) * 4 + len(set(planes.tolist())) * d * f * 4
     ai = flops / bytes_
     rows.append({
         "kernel": "slot_gmm", "ref_us": round(t_ref * 1e6, 1),

@@ -4,7 +4,7 @@ Execution per decode step (the paper's §4 loop, DESIGN.md §2 "engine path"):
 
   embed -> for each layer:
     attn half (device jit) -> fused router top-k ON DEVICE (Pallas topk_gate on
-    TPU, lax.top_k on CPU) -> gathered slot compute against the
+    TPU, lax.top_k on CPU) -> slot compute grouped by slot against the
     persistent device LUT (misses classified in-kernel, dropped) ->
     pre-gating: layer l's hidden predicts layer l+1's demand; the manager
     rotates l+1's slots and issues uploads BEFORE l+1 executes (double-buffered
@@ -69,8 +69,8 @@ Chunked prefill hot path (``prefill_chunk=C``)
 Prompts ingest in power-of-two chunks (``prefill_chunk_plan`` bounds the
 compile cache): each chunk is ONE compiled whole-stack launch
 (``build_fused_prefill_step`` wrapping ``tfm.prefill_chunk_model`` — chunk
-attention appends to the donated KV state, the MoE half gathers all B*C
-chunk tokens through the same ``stacked_residency()`` pytree decode uses)
+attention appends to the donated KV state, the MoE half runs all B*C
+chunk tokens grouped by slot through the same ``stacked_residency()`` pytree decode uses)
 plus ONE queue-draining pull and ONE coalesced rotation window at the chunk
 boundary (the pre-gating demand GEMM over the chunk's stacked hiddens, EMA
 fold, ring transition per layer, uploads batched to one scatter per weight
@@ -309,9 +309,10 @@ def build_fused_prefill_step(
     residency) -> (logits [B, V], new_state, aux)`` wrapping
     :func:`tfm.prefill_chunk_model`: the chunk's C positions run through the
     whole stack (embed -> every layer -> lm head) in one launch, appending to
-    the DONATED KV state, gathering experts for all B*C chunk tokens from the
-    same ``stacked_residency()`` pytree decode uses, and emitting the same
-    ``route_*`` telemetry decode does. The engine calls this with
+    the DONATED KV state, running the routed experts of all B*C chunk tokens
+    grouped by slot over the same ``stacked_residency()`` pytree decode uses,
+    and emitting the same ``route_*`` telemetry decode does. The engine calls
+    this with
     ``with_demand=False`` so the raw per-layer hiddens (``route_h``) stay in
     the aux for the chunk-boundary demand GEMM (which must see
     replay-corrected hiddens — an in-graph demand would bake in the
@@ -1797,9 +1798,16 @@ class RotaryEngine:
                     # non-final chunks have no head output: the first telemetry
                     # read is their one queue-draining pull instead
                     ids = concat_route_telemetry(aux, "ids", self._moe_segs)
-                with maybe_span(tr, "telemetry", "pull"):
+                counts = {} if tr is not None else None
+                with maybe_span(tr, "telemetry", "pull", args=counts):
                     weights = concat_route_telemetry(aux, "weights", self._moe_segs)
                     miss = concat_route_telemetry(aux, "miss", self._moe_segs)
+                if counts is not None:
+                    # read after the span closes, into the args it recorded:
+                    # the routed picks, and the expert weight planes the
+                    # chunk's MoE layers read for them (device count)
+                    counts.update(picks=int(ids.size),
+                                  rows=int(np.asarray(aux["moe_reads"])))
                 missed = np.flatnonzero(miss.reshape(miss.shape[0], -1).any(axis=1))
                 if tr is not None and missed.size:
                     tr.instant("miss", "launch",

@@ -67,15 +67,35 @@ def decode_attention(
     return out[:, None]                                    # [B,1,H,dh]
 
 
-def moe_slot_ffn(x: jax.Array, slots: dict, lut: jax.Array, **blocks) -> jax.Array:
-    return _gmm.moe_slot_ffn(x, slots, lut, interpret=_interpret(), **blocks)
+def moe_slot_ffn(
+    x: jax.Array, slots: dict, group_sizes: jax.Array, group_planes: jax.Array
+) -> jax.Array:
+    """Routed-expert FFN for the compiled engine paths (traceable): rows
+    sorted by group, each group times its plane of ``slots`` (bf16/f32).
+
+    On TPU this lowers three ``slot_gmm`` kernels, which read each used plane
+    in place; on CPU the same grouping runs through ``jax.lax.ragged_dot``
+    over the groups' planes, since an interpreted kernel inside a jitted hot
+    loop is pure overhead.
+    """
+    if not _interpret():
+        return _gmm.moe_slot_ffn(x, slots, group_sizes, group_planes)
+
+    def matmul(rows, name):
+        w = jnp.take(slots[name], group_planes, axis=0)
+        return jax.lax.ragged_dot(
+            rows, w, group_sizes, preferred_element_type=jnp.float32
+        ).astype(rows.dtype)
+
+    return _gmm.expert_ffn(x, slots, matmul)
 
 
 def slot_gmm(
-    x: jax.Array, w: jax.Array, lut: jax.Array,
+    x: jax.Array, w: jax.Array, group_sizes: jax.Array, group_planes: jax.Array,
     scale: Optional[jax.Array] = None, mn: Optional[jax.Array] = None, **blocks
 ) -> jax.Array:
-    return _gmm.slot_gmm(x, w, lut, scale, mn, interpret=_interpret(), **blocks)
+    return _gmm.slot_gmm(x, w, group_sizes, group_planes, scale, mn,
+                         interpret=_interpret(), **blocks)
 
 
 def topk_gate(logits: jax.Array, k: int, *, normalize: bool = True

@@ -13,33 +13,41 @@ from repro.models.attention import reference_attention
 
 
 def slot_gmm_ref(
-    x: jax.Array,              # [E, C, D] per-expert token batches
-    w: jax.Array,              # [S+1, D, F] slot weights ([S+1, D/2, F] u8 if int4)
-    lut: jax.Array,            # [E] int32 expert -> slot
-    scale: Optional[jax.Array] = None,   # int8: [S+1, F] f32 | int4: [S+1, D/G, F] f16
-    mn: Optional[jax.Array] = None,      # int4: [S+1, D/G, F] f16 group mins
+    x: jax.Array,              # [M, K] rows sorted by group
+    w: jax.Array,              # [P, K, N] slot planes ([P, K/2, N] u8 if int4)
+    group_sizes: jax.Array,    # [G] int32 rows per group
+    group_planes: jax.Array,   # [G] int32 plane each group multiplies
+    scale: Optional[jax.Array] = None,   # int8: [P, N] f32 | int4: [P, K/G, N] f16
+    mn: Optional[jax.Array] = None,      # int4: [P, K/G, N] f16 group mins
 ) -> jax.Array:
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(x.shape[0])
+    group = jnp.minimum(jnp.searchsorted(ends, row, side="right"),
+                        group_sizes.shape[0] - 1)
+    plane = group_planes[group]                                   # [M]
+    live = (row < ends[-1])[:, None]
+    quantized = w.dtype in (jnp.int8, jnp.uint8)
     if w.dtype == jnp.uint8:             # grouped int4: dequant BEFORE the dot
         from repro.quant import dequantize_int4
 
-        wg = jnp.take(dequantize_int4(w, scale, mn), lut, axis=0)
-        out = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32), wg)
-        return out.astype(jnp.float32)
-    wg = jnp.take(w, lut, axis=0).astype(jnp.float32)            # [E, D, F]
-    out = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32), wg)
-    if scale is not None:
-        out = out * jnp.take(scale, lut, axis=0)[:, None, :]
-    return out.astype(x.dtype if scale is None and w.dtype != jnp.int8 else jnp.float32)
+        w = dequantize_int4(w, scale, mn)
+    wr = jnp.take(w, plane, axis=0).astype(jnp.float32)          # [M, K, N]
+    out = jnp.einsum("mk,mkn->mn", x.astype(jnp.float32), wr)
+    if w.dtype == jnp.int8:
+        out = out * jnp.take(scale, plane, axis=0)
+    return jnp.where(live, out, 0).astype(jnp.float32 if quantized else x.dtype)
 
 
 def moe_slot_ffn_ref(
-    x: jax.Array,              # [E, C, D]
+    x: jax.Array,              # [M, D] rows sorted by group
     slots: dict,               # w_gate/w_up/w_down (+ scale_* / min_* when quantized)
-    lut: jax.Array,
+    group_sizes: jax.Array,
+    group_planes: jax.Array,
 ) -> jax.Array:
     def g(name, xx=x):
         return slot_gmm_ref(
-            xx, slots[name], lut, slots.get(f"scale_{name}"), slots.get(f"min_{name}")
+            xx, slots[name], group_sizes, group_planes,
+            slots.get(f"scale_{name}"), slots.get(f"min_{name}"),
         )
 
     if "w_gate" in slots:

@@ -14,10 +14,15 @@ Dispatch implementations (ShardingConfig.moe_impl):
   axis, each device runs ``sorted`` dispatch for its local experts, partial
   outputs reduce-scatter back. Predictable collectives (1 AG + 1 RS per layer).
 
-Decode uses ``moe_gathered``: per-token expert weights are *gathered* (optionally
-through the rotary slot LUT) and applied as grouped GEMVs — exactly active-param
-FLOPs, no capacity padding. This is the compiled half of the paper's technique;
-misses surface as a mask the engine corrects between steps.
+The engine paths (decode, prefill chunks, spec windows, serving ticks) use
+``moe_apply_routed`` with routing computed by the caller. Through the rotary
+slot planes, a step (one decode token or a chunk of many) sorts its picks by
+slot and runs them as one grouped matmul per weight (``kernels/moe_gmm.py``'s
+``slot_gmm`` on TPU), each used slot read once in place — exactly
+active-param FLOPs, no capacity padding. Misses read nothing in the grouped form and surface as a mask the engine
+corrects between steps. This is the compiled half of the paper's technique.
+Without slot planes (no residency) each token's expert weights are gathered
+from the expert store instead.
 """
 from __future__ import annotations
 
@@ -28,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config.base import MoEConfig
+from repro.kernels import ops
 from repro.models.layers import Params, dense_init
 
 Aux = Dict[str, jax.Array]
@@ -263,27 +269,35 @@ def moe_epsum_local(
 
 
 # ---------------------------------------------------------------------------
-# gathered decode: per-token expert weights, optionally through the slot LUT
+# routed experts on the engine paths, through the slot LUT
 # ---------------------------------------------------------------------------
-def _slot_rows(
-    plane: jax.Array, gidx: jax.Array, layer: Optional[jax.Array]
-) -> jax.Array:
-    """The rows ``plane[layer, gidx]`` ([L, S+1, ...] stacked plane) or
-    ``plane[gidx]`` ([S+1, ...] one layer's plane), as [*gidx.shape, ...].
+def _grouped_slot_ffn(
+    x2d: jax.Array, gidx: jax.Array, planes: Params, layer: Optional[jax.Array]
+) -> Tuple[jax.Array, jax.Array]:
+    """Each routed pick's expert FFN output [T, k, D], and the number of
+    groups (slot planes read) it ran, read through the slot
+    planes: one layer's ([S+1, ...]) or the whole stack's ([L, S+1, ...], at
+    ``layer``). ``gidx`` [T, k] holds each pick's slot, S for a miss.
 
-    Each routed pick is one ``lax.dynamic_slice`` of its row, so the plane is
-    read where it lies: a stacked plane closed over by the layer scan is never
-    sliced or copied per layer, only the picked rows move.
+    The T·k picks are sorted by slot and run as one grouped matmul per
+    weight (``ops.moe_slot_ffn``), one group per distinct slot, so each used
+    slot's rows are read once, in place: a stacked plane is flattened to
+    [L·(S+1), ...] (a free reshape) and addressed at ``layer·(S+1) + slot``,
+    never sliced out of the stack. Missed picks sort last and belong to no
+    group: their rows are zero and the zero miss slot is never read.
     """
-    row = plane.shape[-2:]
-    lead = (layer,) if plane.ndim == 4 else ()
-    sizes = (1,) * (len(lead) + 1) + row
-
-    def one(g):
-        start = lead + (g,) + (0,) * len(row)
-        return jax.lax.dynamic_slice(plane, start, sizes).reshape(row)
-
-    return jax.vmap(one)(gidx.reshape(-1)).reshape(gidx.shape + row)
+    t, k = gidx.shape
+    s1 = planes["w_up"].shape[-3]
+    flat = gidx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=s1 - 1)          # the miss slot: no group
+    group_planes = jnp.arange(s1 - 1, dtype=jnp.int32)
+    if planes["w_up"].ndim == 4:
+        group_planes = group_planes + layer * s1
+    stack = {n: v.reshape((-1,) + v.shape[-2:]) for n, v in planes.items()}
+    rows = ops.moe_slot_ffn(x2d[order // k], stack, sizes, group_planes)
+    outs = jnp.zeros_like(rows).at[order].set(rows).reshape(t, k, -1)
+    return outs, jnp.count_nonzero(sizes)
 
 
 def moe_apply_routed(
@@ -296,14 +310,19 @@ def moe_apply_routed(
     lut: Optional[jax.Array] = None,
     layer: Optional[jax.Array] = None,
     include_shared: bool = True,
+    aux: Optional[Aux] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Apply already-routed experts via gathered weights (engine path).
+    """Apply already-routed experts (engine path).
 
-    Same compute as ``moe_gathered`` but routing is supplied by the caller so the
-    rotary engine can resolve the LUT / issue blocking loads BEFORE compute.
-    ``slot_buffer`` planes are one layer's ([S+1, ...]) or the whole stack's
-    ([L, S+1, ...], read at ``layer``); either way only the routed rows are
-    read (:func:`_slot_rows`). Returns (y [T,D], miss [T,k]).
+    Routing is supplied by the caller so the rotary engine can resolve the
+    LUT / issue blocking loads BEFORE compute. ``slot_buffer`` planes are one
+    layer's ([S+1, ...]) or the whole stack's ([L, S+1, ...], read at
+    ``layer``); the picks run grouped by slot (:func:`_grouped_slot_ffn`),
+    one token (decode) or many alike. Without slot planes each pick's
+    weights are gathered from the expert store. Either way the outputs
+    combine with the routing weights in f32. Returns (y [T,D], miss [T,k]).
+    Given ``aux``, also sets ``aux["moe_reads"]``: the expert weight planes
+    the step read, one per slot group run (one per pick when gathered).
     """
     if slot_buffer is not None:
         assert lut is not None
@@ -311,23 +330,24 @@ def moe_apply_routed(
         slots = lut[ids]
         miss = slots >= num_slots
         gidx = jnp.where(miss, num_slots, slots)
-        w = {n: _slot_rows(v, gidx, layer) for n, v in slot_buffer.items()}
+        outs, reads = _grouped_slot_ffn(x2d, gidx, slot_buffer, layer)
     else:
         miss = jnp.zeros(ids.shape, bool)
         w = {n: jnp.take(v, ids, axis=0) for n, v in p["experts"].items()}
-    wq, wd = w["w_up"], w["w_down"]
-    if "w_gate" in w:
-        wg = w["w_gate"]
-        h = jax.nn.silu(jnp.einsum("td,tkdf->tkf", x2d, wg)) * jnp.einsum(
-            "td,tkdf->tkf", x2d, wq
-        )
-    else:
-        h = jax.nn.gelu(jnp.einsum("td,tkdf->tkf", x2d, wq))
-    outs = jnp.einsum("tkf,tkfd->tkd", h, wd)
+        reads = jnp.int32(ids.size)
+        wq, wd = w["w_up"], w["w_down"]
+        if "w_gate" in w:
+            h = jax.nn.silu(jnp.einsum("td,tkdf->tkf", x2d, w["w_gate"])) * (
+                jnp.einsum("td,tkdf->tkf", x2d, wq))
+        else:
+            h = jax.nn.gelu(jnp.einsum("td,tkdf->tkf", x2d, wq))
+        outs = jnp.einsum("tkf,tkfd->tkd", h, wd)
     w_eff = weights * (~miss).astype(jnp.float32)
     y = jnp.einsum("tkd,tk->td", outs.astype(jnp.float32), w_eff).astype(x2d.dtype)
     if include_shared and "shared" in p:
         y = y + _shared_ffn(p, x2d)
+    if aux is not None:
+        aux["moe_reads"] = reads
     return y, miss
 
 

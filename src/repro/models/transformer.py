@@ -5,7 +5,8 @@ Layer stack = ``cfg.segments``: each segment is a unit of block kinds scanned
 ``reps`` times with parameters stacked on axis 0, so HLO size is independent of
 depth. Decode threads a per-layer state pytree (KV caches / recurrent states)
 through the same scan. The MoE FFN implementation is selected by
-``Runtime.sharding.moe_impl``; decode uses the gathered per-token path which is
+``Runtime.sharding.moe_impl``; decode and prefill chunks use the routed path
+(``moe.moe_apply_routed``: picks grouped by slot through the slot LUT), which is
 also the compiled half of the rotary-residency technique (slot buffers + LUT).
 """
 from __future__ import annotations
@@ -354,7 +355,7 @@ def _apply_block(
                 else:
                     y2, miss = moe_mod.moe_apply_routed(
                         p["moe"], h2d, ids, weights,
-                        slot_buffer=slot_buffer, lut=lut, layer=layer,
+                        slot_buffer=slot_buffer, lut=lut, layer=layer, aux=aux,
                     )
                 aux["moe_miss"] = miss.sum()
                 # routing telemetry for the rotary engine/predictor ("route_*"
@@ -744,8 +745,8 @@ def prefill_chunk_model(
 
     The multi-token sibling of :func:`decode_model` — the same stacked scan,
     ``"chunk"`` mode blocks (:func:`attention_prefill_chunk` appends the
-    chunk's KV; the MoE half runs the routed/gathered path over all B*C chunk
-    tokens, optionally through the residency slot LUT, emitting the same
+    chunk's KV; the MoE half runs the routed path over all B*C chunk tokens,
+    grouped by slot through the residency slot LUT, emitting the same
     ``route_*`` telemetry decode does). Requires KV-cache-only block kinds.
 
     Returns (logits [B, V] at the chunk's LAST position, new state, aux);
@@ -783,7 +784,7 @@ def decode_window(
     ``cur_len`` (scalar engine or per-row [B] serving batches) and drafts the
     next token on-device — the self-drafting half of the speculative decode
     path. The residency pytree is a scan constant, so every window position
-    gathers from the SAME residency snapshot (rotation is the caller's job, at
+    reads the SAME residency snapshot (rotation is the caller's job, at
     window boundaries).
 
     Drafting is a plain argmax by default. With ``sample`` (a static

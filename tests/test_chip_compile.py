@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.config import get_config
 from repro.configs import cut_depth
 from repro.core.engine import build_fused_decode_step, build_fused_prefill_step
+from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.moe_gmm import slot_gmm
 from repro.kernels.topk_gate import topk_gate
@@ -101,26 +102,27 @@ def test_decode_attention_compiles_for_v5e(one_chip, no_compile_cache, b):
 @pytest.mark.parametrize("quant", [None, "int8", "int4"])
 @pytest.mark.parametrize("name", ["w_up", "w_down"])
 def test_slot_gmm_compiles_for_v5e(one_chip, no_compile_cache, quant, name):
-    # qwen36-35b-a3b experts: E=128 routed through 32 slots (+1 miss slot),
-    # D=2048, F=768; w_down contracts over F instead of D
-    e, slots, c, group = 128, 33, 128, 64
+    # qwen36-35b-a3b experts: a 256-token chunk's 2048 picks (top-8) sorted
+    # into 32 slot groups of a 33-slot plane (+1 miss slot), D=2048, F=768;
+    # w_down contracts over F instead of D
+    m, groups, slots, group = 256 * 8, 32, 33, 64
     d, f = (2048, 768) if name == "w_up" else (768, 2048)
-    x = _spec(one_chip, (e, c, d), jnp.bfloat16)
-    lut = _spec(one_chip, (e,), jnp.int32)
+    x = _spec(one_chip, (m, d), jnp.bfloat16)
+    meta = (_spec(one_chip, (groups,), jnp.int32),) * 2
     if quant is None:
         w = _spec(one_chip, (slots, d, f), jnp.bfloat16)
-        _compile(lambda x, w, lut: slot_gmm(x, w, lut), x, w, lut)
+        _compile(lambda x, w, n, p: slot_gmm(x, w, n, p), x, w, *meta)
     elif quant == "int8":
         w = _spec(one_chip, (slots, d, f), jnp.int8)
         sc = _spec(one_chip, (slots, f), jnp.float32)
-        _compile(lambda x, w, lut, sc: slot_gmm(x, w, lut, sc), x, w, lut, sc)
+        _compile(lambda x, w, n, p, sc: slot_gmm(x, w, n, p, sc), x, w, *meta, sc)
     else:
         w = _spec(one_chip, (slots, d // 2, f), jnp.uint8)
         sc = _spec(one_chip, (slots, d // group, f), jnp.float16)
         mn = _spec(one_chip, (slots, d // group, f), jnp.float16)
         _compile(
-            lambda x, w, lut, sc, mn: slot_gmm(x, w, lut, sc, mn),
-            x, w, lut, sc, mn,
+            lambda x, w, n, p, sc, mn: slot_gmm(x, w, n, p, sc, mn),
+            x, w, *meta, sc, mn,
         )
 
 
@@ -161,16 +163,20 @@ def fused_qwen2_moe(one_chip, no_compile_cache):
     cur_len = _spec(one_chip, (), jnp.int32)
     decode = build_fused_decode_step(cfg, rt, with_demand=False)
     chunk = build_fused_prefill_step(cfg, rt, with_demand=False, with_head=False)
-    return {
-        "decode": decode.lower(
-            params, None, _spec(one_chip, (1,), jnp.int32), state, cur_len,
-            residency,
-        ).compile(),
-        "chunk": chunk.lower(
-            params, None, _spec(one_chip, (1, 16), jnp.int32), state, cur_len,
-            residency,
-        ).compile(),
-    }
+    with pytest.MonkeyPatch.context() as mp:
+        # traced where JAX's backend is the CPU: take the kernel wrappers'
+        # TPU branch, as the steps do on the chip
+        mp.setattr(ops, "_interpret", lambda: False)
+        return {
+            "decode": decode.lower(
+                params, None, _spec(one_chip, (1,), jnp.int32), state, cur_len,
+                residency,
+            ).compile(),
+            "chunk": chunk.lower(
+                params, None, _spec(one_chip, (1, 16), jnp.int32), state,
+                cur_len, residency,
+            ).compile(),
+        }
 
 
 @pytest.mark.parametrize("step", ["decode", "chunk"])
@@ -185,3 +191,20 @@ def test_fused_step_reads_only_routed_rows_on_v5e(fused_qwen2_moe, step):
 def test_fused_decode_temp_below_one_plane_on_v5e(fused_qwen2_moe):
     temp = fused_qwen2_moe["decode"].memory_analysis().temp_size_in_bytes
     assert temp < _PLANE_BYTES
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+def test_fused_step_runs_routed_experts_as_grouped_gemm_on_v5e(
+        fused_qwen2_moe, step):
+    """The routed picks of decode (4) and of the 16-token chunk (64) run as
+    the grouped slot GEMM kernel, with no per-pick [T·k, 1, 1, D, F] weight
+    copy (bf16[64,1,1,2048,1408] in the chunk before) and temporaries below
+    one plane."""
+    compiled = fused_qwen2_moe[step]
+    hlo = compiled.as_text()
+    # the kernel's own instruction (the name alone also shows up in the
+    # text's table of traced frames)
+    assert re.search(r"%slot_gmm\S* = [^\n]*tpu_custom_call", hlo)
+    for a, b in ((_D, _F), (_F, _D)):
+        assert not re.search(rf"bf16\[\d+,1,1,{a},{b}\]", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < _PLANE_BYTES

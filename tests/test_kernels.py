@@ -5,51 +5,80 @@ import numpy as np
 import pytest
 
 from repro.core.slots import quantize_int8
-from repro.kernels import ops, ref
+from repro.kernels import moe_gmm, ops, ref
+
+
+def _groups(rng, g, c, p):
+    """Ragged groups over ``g * c`` rows: sizes in [0, 2c) with group 1
+    empty and the rows past the last group left over, and random planes
+    (repeats allowed) out of ``p``."""
+    sizes = rng.integers(0, 2 * c, g)
+    sizes[1] = 0
+    while sizes.sum() > g * c:
+        sizes[np.argmax(sizes)] -= 1
+    return (jnp.asarray(sizes, jnp.int32),
+            jnp.asarray(rng.integers(0, p, g), jnp.int32))
 
 
 @pytest.mark.parametrize("e,c,d,f,s", [(4, 8, 16, 32, 3), (6, 16, 32, 16, 6),
                                        (2, 4, 8, 8, 1)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_slot_gmm_sweep(rng, e, c, d, f, s, dtype):
-    x = jnp.asarray(rng.standard_normal((e, c, d)), dtype)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), dtype)
     w = jnp.asarray(rng.standard_normal((s + 1, d, f)), dtype)
     w = w.at[-1].set(0.0)
-    lut = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
-    out = ops.slot_gmm(x, w, lut, block_c=4, block_f=8, block_d=8)
-    r = ref.slot_gmm_ref(x, w, lut)
+    sizes, planes = _groups(rng, e, c, s + 1)
+    out = ops.slot_gmm(x, w, sizes, planes, block_m=4, block_k=8, block_n=8)
+    r = ref.slot_gmm_ref(x, w, sizes, planes)
     atol = 1e-4 if dtype == jnp.float32 else 0.1
+    assert out.dtype == dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(r, np.float32), atol=atol)
+    assert not np.asarray(out[int(sizes.sum()):], np.float32).any()
+
+
+def test_slot_gmm_no_rows(rng):
+    """Every group empty (every pick missed): one visit that writes nothing,
+    and all rows zero."""
+    x = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((3, 16, 8)), jnp.float32)
+    out = ops.slot_gmm(x, w, jnp.zeros((3,), jnp.int32),
+                       jnp.arange(3, dtype=jnp.int32), block_m=4)
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
 
 
 def test_slot_gmm_int8(rng):
     e, c, d, f, s = 4, 8, 16, 24, 3
-    x = jnp.asarray(rng.standard_normal((e, c, d)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), jnp.float32)
     wf = rng.standard_normal((s + 1, d, f)).astype(np.float32)
     q = np.zeros((s + 1, d, f), np.int8)
     sc = np.zeros((s + 1, f), np.float32)
     for i in range(s):
         q[i], sc[i] = quantize_int8(wf[i])
-    lut = jnp.asarray([0, 2, 1, 3], jnp.int32)
-    out = ops.slot_gmm(x, jnp.asarray(q), lut, jnp.asarray(sc),
-                       block_c=4, block_f=8, block_d=8)
-    r = ref.slot_gmm_ref(x, jnp.asarray(q), lut, jnp.asarray(sc))
+    sizes, planes = _groups(rng, e, c, s + 1)
+    out = ops.slot_gmm(x, jnp.asarray(q), sizes, planes, jnp.asarray(sc),
+                       block_m=4, block_k=8, block_n=8)
+    r = ref.slot_gmm_ref(x, jnp.asarray(q), sizes, planes, jnp.asarray(sc))
     np.testing.assert_allclose(np.asarray(out), np.asarray(r), atol=1e-4)
 
 
 def test_moe_slot_ffn_matches_ref(rng):
     e, c, d, f, s = 4, 8, 16, 24, 5
-    x = jnp.asarray(rng.standard_normal((e, c, d)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), jnp.float32)
     slots = {
         "w_gate": jnp.asarray(rng.standard_normal((s + 1, d, f)), jnp.float32),
         "w_up": jnp.asarray(rng.standard_normal((s + 1, d, f)), jnp.float32),
         "w_down": jnp.asarray(rng.standard_normal((s + 1, f, d)), jnp.float32),
     }
-    lut = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
-    out = ops.moe_slot_ffn(x, slots, lut, block_c=4, block_f=8, block_d=8)
-    r = ref.moe_slot_ffn_ref(x, slots, lut)
+    sizes, planes = _groups(rng, e, c, s + 1)
+    out = moe_gmm.moe_slot_ffn(x, slots, sizes, planes, interpret=True,
+                               block_m=4, block_k=8, block_n=8)
+    r = ref.moe_slot_ffn_ref(x, slots, sizes, planes)
     np.testing.assert_allclose(np.asarray(out), np.asarray(r, np.float32),
+                               atol=2e-4)
+    # the engine's CPU path (ragged_dot over the groups' planes) agrees
+    cpu = ops.moe_slot_ffn(x, slots, sizes, planes)
+    np.testing.assert_allclose(np.asarray(cpu), np.asarray(r, np.float32),
                                atol=2e-4)
 
 

@@ -363,6 +363,26 @@ def test_unit_spans_lie_inside_their_step_in_phase_order():
             assert first["account"] <= first["demand"] <= first["rotation"]
 
 
+def test_chunk_telemetry_spans_carry_picks_and_rows():
+    """Each fused chunk's ``telemetry`` span carries the chunk's picks and
+    the expert weight planes its MoE layers read (what
+    ``expert_rows_per_pick`` reads); decode units' carry nothing."""
+    eng, tr = _chunked()
+    chunks = 0
+    for kind, recs in _units(tr).values():
+        for r in recs:
+            if r[1] != "telemetry":
+                continue
+            if kind == "chunk":
+                chunks += 1
+                assert r[7]["picks"] == (eng.num_moe_layers * 8
+                                         * eng.cfg.moe.top_k)
+                assert 0 < r[7]["rows"] <= r[7]["picks"]
+            else:
+                assert not r[7]
+    assert chunks == 1
+
+
 def test_spans_are_forwarded_to_the_profiler_host_plane(tmp_path):
     import glob
 

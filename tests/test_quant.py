@@ -150,23 +150,24 @@ def test_slot_gmm_int4_matches_ref(rng):
     from repro.kernels import ops, ref
 
     e, c, d, f, s = 4, 8, 16, 24, 3
-    x = jnp.asarray(rng.standard_normal((e, c, d)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), jnp.float32)
     wf = rng.standard_normal((s + 1, d, f)).astype(np.float32)
     wf[-1] = 0.0
     packed, scale, mn = quantize_int4(wf, 8)
-    lut = jnp.asarray([0, 2, 1, 3], jnp.int32)
-    out = ops.slot_gmm(x, jnp.asarray(packed), lut, jnp.asarray(scale),
-                       jnp.asarray(mn), block_c=4, block_f=8, block_d=8)
-    r = ref.slot_gmm_ref(x, jnp.asarray(packed), lut, jnp.asarray(scale),
-                         jnp.asarray(mn))
+    sizes = jnp.full((e,), c, jnp.int32)            # c rows per expert
+    planes = jnp.asarray([0, 2, 1, 3], jnp.int32)   # expert -> slot
+    out = ops.slot_gmm(x, jnp.asarray(packed), sizes, planes, jnp.asarray(scale),
+                       jnp.asarray(mn), block_m=4, block_k=8, block_n=8)
+    r = ref.slot_gmm_ref(x, jnp.asarray(packed), sizes, planes,
+                         jnp.asarray(scale), jnp.asarray(mn))
     np.testing.assert_allclose(np.asarray(out), np.asarray(r), atol=1e-4)
 
 
 def test_moe_slot_ffn_int4_matches_ref(rng):
-    from repro.kernels import ops, ref
+    from repro.kernels import moe_gmm, ref
 
     e, c, d, f, s = 4, 8, 16, 24, 5
-    x = jnp.asarray(rng.standard_normal((e, c, d)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((e * c, d)), jnp.float32)
     slots = {}
     for name, shape in (("w_gate", (d, f)), ("w_up", (d, f)), ("w_down", (f, d))):
         wq = rng.standard_normal((s + 1,) + shape).astype(np.float32)
@@ -174,9 +175,11 @@ def test_moe_slot_ffn_int4_matches_ref(rng):
         slots[name] = jnp.asarray(p)
         slots[f"scale_{name}"] = jnp.asarray(sc)
         slots[f"min_{name}"] = jnp.asarray(mn)
-    lut = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
-    out = ops.moe_slot_ffn(x, slots, lut, block_c=4, block_f=8, block_d=8)
-    r = ref.moe_slot_ffn_ref(x, slots, lut)
+    sizes = jnp.full((e,), c, jnp.int32)
+    planes = jnp.asarray(rng.integers(0, s + 1, e), jnp.int32)
+    out = moe_gmm.moe_slot_ffn(x, slots, sizes, planes, interpret=True,
+                               block_m=4, block_k=8, block_n=8)
+    r = ref.moe_slot_ffn_ref(x, slots, sizes, planes)
     np.testing.assert_allclose(np.asarray(out), np.asarray(r), atol=2e-4)
 
 
